@@ -2,97 +2,38 @@
 the DMR API against an in-process RMS, resharding its TrainState on the
 fly (the paper's §5 protocol, end to end).
 
-Needs >1 device, so this entry point (like the dry-run) requests CPU host
-devices BEFORE jax initializes.
+The job starts on every device JAX finds.  A scripted rival job queues
+for half of them, so the RMS shrinks the job; when the rival finishes the
+job expands back.  It needs at least 2 devices: on a TPU host, its chips;
+without an accelerator, CPU host devices:
 
-  PYTHONPATH=src python examples/elastic_train.py
+  XLA_FLAGS=--xla_force_host_platform_device_count=8 \\
+      PYTHONPATH=src python examples/elastic_train.py
 """
-import os
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=8")
+import jax
 
-import dataclasses  # noqa: E402
+from repro.launch.train import build
+from repro.runtime import scripted_rival
 
-import jax  # noqa: E402
-
-from repro.core import make_mesh  # noqa: E402
-from repro.data import DataConfig  # noqa: E402
-from repro.models import (build_model, get_model,  # noqa: E402
-                          reduced_config)
-from repro.optim import AdamWConfig  # noqa: E402
-from repro.rms.job import Job, JobState  # noqa: E402
-from repro.runtime import ElasticTrainer, LocalRMS, TrainerConfig  # noqa: E402
+STEPS, CHECK = 120, 20
 
 
 def main():
-    print(f"devices: {len(jax.devices())}")
-    rms = LocalRMS(num_nodes=8)
-    # our job starts on 4 slices
-    job = Job(job_id=0, app="lm:smollm", submit_time=0.0, work=1e9,
-              min_nodes=1, max_nodes=8, preferred=None, requested_nodes=4)
-    rms.submit(job, start=True)
-
-    _, full = get_model("smollm-135m")
-    cfg = dataclasses.replace(reduced_config(full), vocab_size=4096)
-    model = build_model(cfg)
-    data = DataConfig(vocab_size=cfg.vocab_size, seq_len=64,
-                      global_batch=8)
-    opt = AdamWConfig(lr=3e-3, warmup_steps=10, total_steps=120)
-    trainer = ElasticTrainer(
-        model, opt, data,
-        TrainerConfig(steps=120, model_ways=1, min_slices=1, max_slices=8,
-                      check_period=20, log_period=20),
-        rms=rms, job_id=0)
-    trainer.slices = 4
-    trainer.mesh = make_mesh(4, 1)
-    trainer.dmr.current_slices = 4
-
-    # Script the cluster: at step ~40 a rival job takes nodes (we shrink
-    # via wide-optimization); at step ~80 it finishes (we expand back).
-    events = {40: "submit", 80: "finish"}
-    rival = Job(job_id=1, app="lm:smollm", submit_time=0.0, work=1e9,
-                min_nodes=4, max_nodes=4, preferred=None, requested_nodes=4)
-
-    state = trainer.init_state()
-    step = 0
-    while step < 120:
-        if step in events:
-            if events[step] == "submit":
-                rms.submit(rival)          # queued rival -> policy shrinks us
-                print(f"[step {step}] rival job queued (wants 4 nodes)")
-            else:
-                for j in rms.jobs:
-                    if j.job_id == 1 and j.state is JobState.RUNNING:
-                        rms.finish(1)
-                        print(f"[step {step}] rival finished, nodes free")
-        if step > 0 and step % trainer.cfg.check_period == 0:
-            before = trainer.slices
-            state = trainer.maybe_reconfigure(state)
-            if trainer.slices != before:
-                print(f"[step {step}] DMR resize {before} -> "
-                      f"{trainer.slices} slices "
-                      f"(resize {trainer.resize_log[-1]['resize_s']*1e3:.0f}"
-                      f" ms)")
-                # a shrink frees nodes: the RMS can start the rival
-                for j in rms.jobs:
-                    if j.state is JobState.PENDING and \
-                            j.requested_nodes <= rms.cluster.free_nodes:
-                        rms.cluster.allocate(j.job_id, j.requested_nodes)
-                        j.state = JobState.RUNNING
-                        j.nodes = j.requested_nodes
-                        print(f"[step {step}] rival job started on "
-                              f"{j.nodes} nodes")
-        batch = trainer.data.batch(step)
-        fn = trainer.step_fn(trainer.mesh)
-        with trainer.mesh:
-            state, metrics = fn(state, batch)
-        step += 1
-        if step % 20 == 0:
-            print(f"step {step:4d} loss {float(metrics['loss']):.4f} "
-                  f"slices {trainer.slices}")
-    print("\nresize log:", trainer.resize_log)
-    assert any(r["action"] == "SHRINK" for r in trainer.resize_log)
-    assert any(r["action"] == "EXPAND" for r in trainer.resize_log)
+    print(f"devices: {jax.device_count()}")
+    trainer, rms = build("smollm-135m", reduced=True, seq_len=64,
+                         global_batch=8, steps=STEPS,
+                         slices=jax.device_count(), elastic=True,
+                         check_period=CHECK)
+    trainer.train(on_step=scripted_rival(rms, submit_at=2 * CHECK,
+                                         finish_at=4 * CHECK, log=print))
+    for m in trainer.metrics:
+        print(f"step {m['step']:4d} loss {m['loss']:.4f} "
+              f"slices {m['slices']}")
+    for r in trainer.resize_log:
+        print(f"[step {r['step']}] DMR {r['action']} {r['from']} -> "
+              f"{r['to']} slices (resize {r['resize_s'] * 1e3:.0f} ms)")
+    actions = [r["action"] for r in trainer.resize_log]
+    assert actions == ["SHRINK", "EXPAND"], actions
     print("OK: job shrank under queue pressure and expanded back — the "
           "paper's malleability loop, live.")
 

@@ -27,7 +27,6 @@ from typing import List, Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -141,8 +140,8 @@ def migrate_slice(x: jax.Array, mesh: Mesh, src: int, dst: int,
     def body(blk):
         return jax.lax.ppermute(blk, axis, perm)
 
-    fn = shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=spec, out_specs=spec,
+                       check_vma=False)
     # Collapse other mesh axes by treating them as replicated for this op.
     del other_axes
     return fn(x)

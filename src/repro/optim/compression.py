@@ -16,7 +16,6 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 BLOCK = 256
 
@@ -92,7 +91,7 @@ def make_compressed_allreduce(mesh: Mesh, param_specs):
         return compressed_psum_grads(grads, mesh, axes=axes, errors=errors)
 
     specs = jax.tree.map(lambda s: s.spec, param_specs)
-    fn = shard_map(body, mesh=mesh,
-                   in_specs=(specs, specs), out_specs=(specs, specs),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh,
+                       in_specs=(specs, specs), out_specs=(specs, specs),
+                       check_vma=False)
     return jax.jit(fn)

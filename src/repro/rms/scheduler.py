@@ -230,7 +230,11 @@ class EasyBackfillPolicy(SchedulingPolicy):
         avail = free
         shadow_time: Optional[float] = None
         shadow_free_at_reservation = 0
-        for t, n in self._releases(running, now, runtime_estimate):
+        # the jobs just started ahead of the head release their nodes too
+        releases = sorted(self._releases(running, now, runtime_estimate) + [
+            (self._est_end(j, s, now, runtime_estimate), s)
+            for j, s in starts])
+        for t, n in releases:
             avail += n
             if avail >= head_need:
                 shadow_time = t

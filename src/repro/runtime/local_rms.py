@@ -10,7 +10,7 @@ from __future__ import annotations
 
 import threading
 import time
-from typing import List, Optional, Tuple
+from typing import Callable, List, Optional, Tuple
 
 from repro.core.actions import Action, Decision
 from repro.rms.cluster import Cluster
@@ -29,15 +29,28 @@ class LocalRMS:
         self.jobs: List[Job] = []
         self._lock = threading.Lock()
 
+    def _start(self, job: Job) -> None:
+        self.cluster.allocate(job.job_id, job.requested_nodes)
+        job.nodes = job.requested_nodes
+        job.state = JobState.RUNNING
+        job.start_time = time.monotonic()
+
     def submit(self, job: Job, start: bool = False) -> Job:
         with self._lock:
             self.jobs.append(job)
             if start:
-                self.cluster.allocate(job.job_id, job.requested_nodes)
-                job.nodes = job.requested_nodes
-                job.state = JobState.RUNNING
-                job.start_time = time.monotonic()
+                self._start(job)
         return job
+
+    def start_pending(self) -> List[Job]:
+        """Start the queued jobs that fit the free nodes, in queue order."""
+        with self._lock:
+            started = []
+            for job in self.pending():
+                if job.requested_nodes <= self.cluster.free_nodes:
+                    self._start(job)
+                    started.append(job)
+            return started
 
     def finish(self, job_id: int) -> None:
         with self._lock:
@@ -76,3 +89,34 @@ class LocalRMS:
         # Single-controller: the resize transaction in request_reconfig is
         # atomic, so the RJ is already running by construction.
         return True, 0.0
+
+
+def scripted_rival(rms: LocalRMS, *, submit_at: int, finish_at: int,
+                   log: Callable[[str], None] = lambda msg: None
+                   ) -> Callable[[int], None]:
+    """An ``on_step`` hook for :meth:`ElasticTrainer.train` that scripts the
+    cluster around a malleable job holding all of ``rms``'s nodes.
+
+    At step ``submit_at`` a rival job asking for half the nodes joins the
+    queue, so the policy's wide optimization shrinks the job; once the
+    rival has started on the freed nodes and reached step ``finish_at`` it
+    finishes, and the next reconfiguration point expands the job back.
+    """
+    half = rms.cluster.num_nodes // 2
+    if half < 1:
+        raise ValueError("a rival needs a cluster of at least 2 nodes")
+    rival = Job(job_id=1, app="rival", submit_time=0.0, work=1e9,
+                min_nodes=half, max_nodes=half, preferred=None,
+                requested_nodes=half)
+
+    def on_step(step: int) -> None:
+        if step == submit_at:
+            rms.submit(rival)
+            log(f"[step {step}] rival job queued (wants {half} nodes)")
+        if step == finish_at and rival.state is JobState.RUNNING:
+            rms.finish(rival.job_id)
+            log(f"[step {step}] rival finished, {half} nodes free")
+        for job in rms.start_pending():
+            log(f"[step {step}] job {job.job_id} started on "
+                f"{job.nodes} nodes")
+    return on_step

@@ -5,18 +5,19 @@ The training loop exposes *reconfiguration points* at step boundaries: every
 the mesh to the granted slice count and reshards the entire TrainState
 (params + AdamW moments + RNG + step) via ``repro.core.reshard`` —
 runtime data redistribution, not checkpoint restart.  Checkpoint/restart
-is the *fault* path: any step failure restores the last checkpoint, onto a
-smaller mesh if devices were lost (shrink-to-survivors).
+is the *fault* path: a runtime failure of a step restores the last
+checkpoint, onto a smaller mesh if devices were lost (shrink-to-survivors).
+A failure that comes back before the run has passed the step where it
+first struck (a compile error, an out-of-memory) is re-raised as it is.
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Any, Callable, Dict, Optional
+from typing import Callable, Dict, Optional
 
 import jax
 import jax.numpy as jnp
-import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (DMR, TP_DP_RULES, Action, ShardingRules, make_mesh,
@@ -34,6 +35,9 @@ class TrainerConfig:
     check_period: int = 10            # steps between reconfiguration points
     min_slices: int = 1
     max_slices: int = 8
+    # slices the job starts on; None = as many as the devices hold, up to
+    # max_slices (an elastic job starts on what the RMS granted it)
+    slices: Optional[int] = None
     factor: int = 2
     preferred: Optional[int] = None
     model_ways: int = 1               # TP width inside a slice
@@ -54,8 +58,8 @@ class ElasticTrainer:
         self.data_cfg = data_cfg
         self.cfg = cfg
         self.devices = devices if devices is not None else jax.devices()
-        self.slices = min(cfg.max_slices,
-                          len(self.devices) // cfg.model_ways)
+        self.slices = cfg.slices or min(cfg.max_slices,
+                                        len(self.devices) // cfg.model_ways)
         self.mesh = make_mesh(self.slices, cfg.model_ways,
                               devices=self.devices)
         self.dmr = DMR(rms, job_id, current_slices=self.slices) \
@@ -106,17 +110,26 @@ class ElasticTrainer:
 
     # -- state ---------------------------------------------------------------
 
+    def _fresh_state(self, seed: int):
+        params = self.model.init(jax.random.PRNGKey(seed))
+        return {"params": params, "opt": init_state(params),
+                "rng": jax.random.PRNGKey(seed + 1),
+                "step": jnp.zeros((), jnp.int32)}
+
     def init_state(self, seed: int = 0):
         shardings = self._state_shardings(self.mesh)
-
-        def make():
-            params = self.model.init(jax.random.PRNGKey(seed))
-            return {"params": params, "opt": init_state(params),
-                    "rng": jax.random.PRNGKey(seed + 1),
-                    "step": jnp.zeros((), jnp.int32)}
         with self.mesh:
-            state = jax.jit(make, out_shardings=shardings)()
+            state = jax.jit(lambda: self._fresh_state(seed),
+                            out_shardings=shardings)()
         return state
+
+    def restore(self, step: int):
+        """Load checkpoint ``step`` onto the current mesh.  The template is
+        abstract (``eval_shape``): nothing but the restored state is placed
+        on the devices."""
+        template = jax.eval_shape(lambda: self._fresh_state(0))
+        return self.store.restore(step, template,
+                                  self._state_shardings(self.mesh))
 
     # -- the jitted step -------------------------------------------------------
 
@@ -196,12 +209,18 @@ class ElasticTrainer:
 
     # -- loop -----------------------------------------------------------------
 
-    def train(self, state=None, seed: int = 0, on_step=None):
+    def train(self, state=None, seed: int = 0,
+              on_step: Optional[Callable[[int], None]] = None):
+        """Run to ``cfg.steps``.  ``on_step(step)`` is called before each
+        step's reconfiguration point (e.g. to script the cluster)."""
         if state is None:
             state = self.init_state(seed)
         start = int(state["step"])
         step = start
+        failed_at = None
         while step < self.cfg.steps:
+            if on_step is not None:
+                on_step(step)
             if self.dmr is not None and step > start and \
                     step % self.cfg.check_period == 0:
                 state = self.maybe_reconfigure(state)
@@ -210,9 +229,14 @@ class ElasticTrainer:
             try:
                 with self.mesh:
                     state, metrics = fn(state, batch)
-            except Exception:
-                state = self._recover()
-                step = int(state["step"])
+            except jax.errors.JaxRuntimeError:
+                restart = self._restart_step()
+                if restart is None or (failed_at is not None
+                                       and step <= failed_at):
+                    raise
+                failed_at = step
+                state = self.restore(restart)
+                step = restart
                 continue
             step += 1
             if step % self.cfg.log_period == 0 or step == self.cfg.steps:
@@ -226,14 +250,10 @@ class ElasticTrainer:
             self.store.wait()
         return state
 
-    def _recover(self):
-        """Fault path: restore the latest checkpoint onto the current
-        (possibly shrunken) mesh."""
+    def _restart_step(self) -> Optional[int]:
+        """Fault path: the checkpoint to restore onto the current (possibly
+        shrunken) mesh, or None when there is none to restore."""
         if self.store is None:
-            raise RuntimeError("step failed and no checkpoint store")
-        step = self.store.latest_step()
-        if step is None:
-            raise RuntimeError("step failed before first checkpoint")
-        template = self.init_state()
-        shardings = self._state_shardings(self.mesh)
-        return self.store.restore(step, template, shardings)
+            return None
+        self.store.wait()
+        return self.store.latest_step()

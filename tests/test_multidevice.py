@@ -116,15 +116,10 @@ def test_elastic_training_expand_matches_fixed():
     def run(rms, slices):
         tr = ElasticTrainer(model, opt, data,
                             TrainerConfig(steps=20, model_ways=1,
+                                          slices=2 if rms else slices,
                                           max_slices=slices,
                                           check_period=5, log_period=5),
                             rms=rms)
-        tr.slices = min(tr.slices, 2) if rms else tr.slices
-        if rms:
-            from repro.core import make_mesh
-            tr.slices = 2
-            tr.mesh = make_mesh(2, 1)
-            tr.dmr.current_slices = 2
         tr.train()
         return [m["loss"] for m in tr.metrics], tr.resize_log
 
@@ -148,7 +143,6 @@ def test_compressed_allreduce_error_feedback_converges():
     out = run_sub("""
     from repro.core import make_mesh
     from repro.optim.compression import compressed_psum_grads
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import NamedSharding, PartitionSpec as P
     mesh = make_mesh(4, 1)
     key = jax.random.PRNGKey(0)
@@ -167,8 +161,8 @@ def test_compressed_allreduce_error_feedback_converges():
                 first_err = mean["g"]
         return first_err[None], (acc / 12)[None]
 
-    fn = shard_map(body, mesh=mesh, in_specs=P("data"),
-                   out_specs=(P("data"), P("data")), check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
+                       out_specs=(P("data"), P("data")), check_vma=False)
     first, avg = fn(g_all)
     truth = np.asarray(g_all).mean(axis=0)
     rel1 = np.abs(np.asarray(first)[0] - truth).max() / \
@@ -180,3 +174,25 @@ def test_compressed_allreduce_error_feedback_converges():
     assert out["rel_single"] < 0.25          # bounded single-shot error
     assert out["rel_avg"] < out["rel_single"]  # EF drives the bias down
     assert out["rel_avg"] < 0.05
+
+
+def test_launcher_elastic_job_shrinks_and_expands_under_a_rival():
+    """The launcher's elastic job holds all 8 devices; a scripted rival
+    makes the RMS shrink it, then the job expands back onto all of them."""
+    out = run_sub("""
+    from repro.launch.train import build
+    from repro.runtime import scripted_rival
+    trainer, rms = build("smollm-135m", reduced=True, seq_len=32,
+                         global_batch=8, steps=6, slices=8, elastic=True,
+                         check_period=2)
+    state = trainer.train(on_step=scripted_rival(rms, submit_at=2,
+                                                 finish_at=4))
+    print(json.dumps({
+        "resizes": [(r["action"], r["to"]) for r in trainer.resize_log],
+        "spans": sorted({len(leaf.sharding.device_set)
+                         for leaf in jax.tree.leaves(state)}),
+        "losses": [m["loss"] for m in trainer.metrics]}))
+    """)
+    assert out["resizes"] == [["SHRINK", 4], ["EXPAND", 8]]
+    assert out["spans"] == [8]
+    assert len(out["losses"]) == 6

@@ -104,6 +104,18 @@ def head_reservation_time(free, head_need, releases):
 def test_easy_backfill_never_delays_head_reservation(seed):
     """Backfilled jobs must leave the blocked head startable no later than
     its reservation computed before backfilling."""
+    check_backfill_keeps_head_reservation(seed)
+
+
+@pytest.mark.parametrize("seed", [151, 3532])
+def test_easy_reservation_counts_jobs_started_in_the_same_pass(seed):
+    """Cases where jobs start ahead of the blocked head: their releases
+    must count towards the head's reservation, or a backfill that ends
+    after it is admitted and delays the head."""
+    check_backfill_keeps_head_reservation(seed)
+
+
+def check_backfill_keeps_head_reservation(seed):
     num_nodes, running, pending, est = rand_case(seed)
     cluster = Cluster(num_nodes)
     occupy(cluster, running)

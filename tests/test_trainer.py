@@ -65,3 +65,49 @@ def test_grad_accum_equivalence():
 
     l1, l2 = run(1), run(2)
     assert max(abs(a - b) for a, b in zip(l1, l2)) < 5e-3
+
+
+def fail_from(tr, first_bad_step):
+    """Make every step from ``first_bad_step`` on fail as a device error
+    that no retry cures would (an out-of-memory, a compile error)."""
+    real = tr.step_fn
+    failures = []
+
+    def step_fn(mesh):
+        fn = real(mesh)
+
+        def step(state, batch):
+            if int(state["step"]) >= first_bad_step:
+                failures.append(int(state["step"]))
+                raise jax.errors.JaxRuntimeError("RESOURCE_EXHAUSTED: boom")
+            return fn(state, batch)
+        return step
+    tr.step_fn = step_fn
+    return failures
+
+
+def test_repeated_step_failure_raises_without_store():
+    tr = make(steps=4)
+    failures = fail_from(tr, 2)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="boom"):
+        tr.train()
+    assert failures == [2]
+
+
+def test_repeated_step_failure_raises_after_one_restore(tmp_path):
+    tr = make(steps=6, ckpt_dir=str(tmp_path), ckpt_period=2)
+    failures = fail_from(tr, 3)
+    with pytest.raises(jax.errors.JaxRuntimeError, match="boom"):
+        tr.train()
+    # restored step 2 once, failed again at step 3, then gave up
+    assert failures == [3, 3]
+    assert tr.store.latest_step() == 2
+
+
+def test_restore_onto_current_mesh_is_bit_equal(tmp_path):
+    tr = make(steps=2, ckpt_dir=str(tmp_path), ckpt_period=2)
+    state = tr.train()
+    restored = tr.restore(2)
+    for a, b in zip(jax.tree.leaves(state), jax.tree.leaves(restored)):
+        assert a.sharding == b.sharding
+        assert (jax.numpy.asarray(a) == jax.numpy.asarray(b)).all()
