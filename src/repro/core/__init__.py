@@ -6,8 +6,8 @@ from repro.core.meshes import (make_mesh, mesh_model_ways, mesh_num_slices,
 from repro.core.redistribute import (Transfer, expand_plan, migrate_slice,
                                      plan_stats, shrink_plan,
                                      transfer_time_s)
-from repro.core.reshard import (checkpoint_reshard, ownership_map, reshard,
-                                state_shardings, timed_reshard)
+from repro.core.reshard import (checkpoint_reshard, moved_bytes,
+                                ownership_map, reshard, state_shardings)
 from repro.core.sharding import (FSDP_RULES, LONG_CONTEXT_RULES, TP_DP_RULES,
                                  ShardingRules, rules_for_shape)
 
@@ -16,7 +16,7 @@ __all__ = [
     "make_mesh", "mesh_num_slices", "mesh_model_ways", "resized_mesh",
     "Transfer", "expand_plan", "shrink_plan", "transfer_time_s",
     "plan_stats",
-    "migrate_slice", "reshard", "checkpoint_reshard", "timed_reshard",
+    "migrate_slice", "reshard", "checkpoint_reshard", "moved_bytes",
     "state_shardings", "ownership_map",
     "ShardingRules", "TP_DP_RULES", "FSDP_RULES", "LONG_CONTEXT_RULES",
     "rules_for_shape",

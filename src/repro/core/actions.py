@@ -33,13 +33,15 @@ class ResizeHandler:
     old_slices: int
     new_slices: int
     resizer_job_id: Optional[int] = None   # expand path: the RJ of §5.2.1
-    granted_at: float = 0.0
     # Filled in by the runtime when the new parallel context exists:
     new_mesh: Any = None
     # Diagnostics for the overhead study (Fig. 3 / Table 2):
     schedule_time_s: float = 0.0           # RMS decision latency
     wait_time_s: float = 0.0               # resizer-job pending->running wait
     resize_time_s: float = 0.0             # data-redistribution time
+    # ... of which the transfer, with the wait for the queued steps:
+    transfer_s: float = 0.0
+    moved_bytes: int = 0                   # bytes the new layout brought
     timed_out: bool = False
 
     @property

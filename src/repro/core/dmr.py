@@ -26,6 +26,8 @@ import time
 from concurrent.futures import Future, ThreadPoolExecutor
 from typing import Callable, Optional, Protocol, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.actions import Action, Decision, ResizeHandler
 
 INHIBITOR_ENV = "DMR_INHIBITOR_SECONDS"
@@ -80,9 +82,11 @@ class DMR:
     def _query(self, minimum: int, maximum: int, factor: int,
                preferred: Optional[int]) -> Decision:
         t0 = self.clock()
-        decision = self.rms.request_reconfig(
-            self.job_id, current=self.current_slices, minimum=minimum,
-            maximum=maximum, factor=factor, preferred=preferred)
+        with TraceAnnotation("dmr.query", job=self.job_id,
+                             current=self.current_slices):
+            decision = self.rms.request_reconfig(
+                self.job_id, current=self.current_slices, minimum=minimum,
+                maximum=maximum, factor=factor, preferred=preferred)
         elapsed = self.clock() - t0
         if decision.schedule_time_s == 0.0:
             import dataclasses as _dc
@@ -95,11 +99,12 @@ class DMR:
             job_id=self.job_id, action=decision.action,
             old_slices=self.current_slices, new_slices=decision.new_slices,
             resizer_job_id=decision.resizer_job_id,
-            schedule_time_s=decision.schedule_time_s,
-            granted_at=self.clock())
+            schedule_time_s=decision.schedule_time_s)
         if decision.action is Action.EXPAND:
-            granted, waited = self.rms.confirm_resize(
-                self.job_id, decision, timeout_s=self.expand_timeout_s)
+            with TraceAnnotation("dmr.expand_wait", job=self.job_id,
+                                 to=decision.new_slices):
+                granted, waited = self.rms.confirm_resize(
+                    self.job_id, decision, timeout_s=self.expand_timeout_s)
             handler.wait_time_s = waited
             if not granted:
                 # §5.2.1: RJ cancelled, action aborted — resources were

@@ -19,8 +19,8 @@ Two paths are provided, mirroring the paper's discussion:
 """
 from __future__ import annotations
 
-import time
-from typing import Any, Callable
+import math
+from typing import Any
 
 import jax
 import numpy as np
@@ -57,14 +57,32 @@ def checkpoint_reshard(state: Any, shardings: Any) -> Any:
     return jax.device_put(host, shardings)
 
 
-def timed_reshard(state: Any, shardings: Any,
-                  impl: Callable[[Any, Any], Any] = reshard):
-    """Reshard and return ``(new_state, seconds)`` — the paper's resize time
-    (Fig. 3 right)."""
-    t0 = time.perf_counter()
-    out = impl(state, shardings)
-    jax.block_until_ready(out)
-    return out, time.perf_counter() - t0
+def moved_bytes(state: Any, shardings: Any) -> int:
+    """Bytes that placing ``state`` on ``shardings`` has to bring to the
+    devices: per leaf and per device of the new sharding, the elements of
+    its new shard outside the shard that device held before, times the item
+    size.  A function of the two layouts alone, whatever does the transfer.
+    """
+    def leaf(x, new) -> int:
+        old = x.sharding.devices_indices_map(x.shape)
+        return x.dtype.itemsize * sum(
+            _outside(_box(idx, x.shape), old.get(dev), x.shape)
+            for dev, idx in new.devices_indices_map(x.shape).items())
+    return sum(jax.tree.leaves(jax.tree.map(leaf, state, shardings)))
+
+
+def _box(index, shape):
+    return [s.indices(dim)[:2] for s, dim in zip(index, shape)]
+
+
+def _outside(box, held, shape) -> int:
+    """Elements of ``box`` outside the shard ``held`` (None: none held)."""
+    size = math.prod(hi - lo for lo, hi in box)
+    if held is None:
+        return size
+    return size - math.prod(max(0, min(hi, h_hi) - max(lo, h_lo))
+                            for (lo, hi), (h_lo, h_hi)
+                            in zip(box, _box(held, shape)))
 
 
 def ownership_map(arr: jax.Array) -> dict:

@@ -70,22 +70,28 @@ def block_apply(params, x, cfg: ModelConfig, kind: str, aux):
     if kind in ("global", "local", "moe"):
         a_kind = "local" if kind == "local" else "global"
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        x = x + attn.attention_apply(params["attn"], h, cfg, kind=a_kind)
+        with jax.named_scope("attn"):
+            x = x + attn.attention_apply(params["attn"], h, cfg, kind=a_kind)
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
         if "router" in params["ffn"]:
-            y, a = moe_mod.moe_apply(params["ffn"], h, cfg)
+            with jax.named_scope("moe"):
+                y, a = moe_mod.moe_apply(params["ffn"], h, cfg)
             aux = aux + a
         else:
-            y = mlp_apply(params["ffn"], h, cfg)
+            with jax.named_scope("mlp"):
+                y = mlp_apply(params["ffn"], h, cfg)
         return x + y, aux
     if kind == "ssd":
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        return x + ssm_mod.ssd_apply(params["mixer"], h, cfg), aux
+        with jax.named_scope("ssd"):
+            return x + ssm_mod.ssd_apply(params["mixer"], h, cfg), aux
     if kind == "rglru":
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        x = x + rglru_mod.rglru_mixer_apply(params["mixer"], h, cfg)
+        with jax.named_scope("rglru"):
+            x = x + rglru_mod.rglru_mixer_apply(params["mixer"], h, cfg)
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
-        return x + mlp_apply(params["ffn"], h, cfg), aux
+        with jax.named_scope("mlp"):
+            return x + mlp_apply(params["ffn"], h, cfg), aux
     raise ValueError(kind)
 
 
@@ -229,17 +235,24 @@ class CausalLM:
                                  cfg.pattern[t], aux)
         return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
 
-    def forward(self, params, tokens, extra_embeds=None):
-        """tokens: (B, S_text). extra_embeds: (B, S_front, E) modality stub
-        prepended to the sequence (VLM patches / audio frames)."""
+    def _hidden(self, params, tokens, extra_embeds=None):
+        """Embedding and trunk: the final hidden states and the aux loss."""
         cfg = self.cfg
         x = embed_apply(params["embed"], tokens, cfg)
         if extra_embeds is not None:
             x = jnp.concatenate([extra_embeds.astype(x.dtype), x], axis=1)
         x = constrain(x, ("batch", "seq", "embed"))
-        x, aux = self._trunk(params, x)
-        logits = unembed_apply(params["embed"], x, cfg)
-        return constrain(logits, ("batch", "seq", "vocab")), aux
+        return self._trunk(params, x)
+
+    def _logits(self, params, x):
+        logits = unembed_apply(params["embed"], x, self.cfg)
+        return constrain(logits, ("batch", "seq", "vocab"))
+
+    def forward(self, params, tokens, extra_embeds=None):
+        """tokens: (B, S_text). extra_embeds: (B, S_front, E) modality stub
+        prepended to the sequence (VLM patches / audio frames)."""
+        x, aux = self._hidden(params, tokens, extra_embeds)
+        return self._logits(params, x), aux
 
     def loss(self, params, batch):
         """batch: tokens (B,S), labels (B,S) [-1 = masked], optional
@@ -250,38 +263,33 @@ class CausalLM:
         labels = jnp.maximum(labels, 0)
         denom = jnp.maximum(mask.sum(), 1)
 
-        if cfg.ce_chunk:
-            # chunked CE: run the trunk once, then unembed + log-softmax
-            # per sequence chunk — the (B, S, V) logits never materialize.
-            x = embed_apply(params["embed"], batch["tokens"], cfg)
-            fr = batch.get("frontend")
-            if fr is not None:
-                x = jnp.concatenate([fr.astype(x.dtype), x], axis=1)
-            x = constrain(x, ("batch", "seq", "embed"))
-            x, aux = self._trunk(params, x)
-            n_front = fr.shape[1] if fr is not None else 0
-            x = x[:, n_front:]
-            s = x.shape[1]
-            c = cfg.ce_chunk
-            total = jnp.zeros((), jnp.float32)
-            for i in range(0, s, c):
-                lg = unembed_apply(params["embed"], x[:, i:i + c], cfg)
-                lp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
-                ll = jnp.take_along_axis(
-                    lp, labels[:, i:i + c, None], axis=-1)[..., 0]
-                total = total + (ll * mask[:, i:i + c]).sum()
-            loss = -total / denom
-            return loss + aux, {"ce": loss, "aux": aux}
-
-        logits, aux = self.forward(params, batch["tokens"],
-                                   batch.get("frontend"))
-        if batch.get("frontend") is not None:
-            # frontend positions carry no labels
-            n_front = batch["frontend"].shape[1]
-            logits = logits[:, n_front:]
-        logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
-        ll = jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
-        loss = -(ll * mask).sum() / denom
+        fr = batch.get("frontend")
+        x, aux = self._hidden(params, batch["tokens"], fr)
+        with jax.named_scope("lm_head_loss"):
+            if cfg.ce_chunk:
+                # chunked CE: unembed + log-softmax per sequence chunk —
+                # the (B, S, V) logits never materialize.
+                n_front = fr.shape[1] if fr is not None else 0
+                x = x[:, n_front:]
+                s = x.shape[1]
+                c = cfg.ce_chunk
+                total = jnp.zeros((), jnp.float32)
+                for i in range(0, s, c):
+                    lg = unembed_apply(params["embed"], x[:, i:i + c], cfg)
+                    lp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
+                    ll = jnp.take_along_axis(
+                        lp, labels[:, i:i + c, None], axis=-1)[..., 0]
+                    total = total + (ll * mask[:, i:i + c]).sum()
+                loss = -total / denom
+            else:
+                logits = self._logits(params, x)
+                if fr is not None:   # frontend positions carry no labels
+                    logits = logits[:, fr.shape[1]:]
+                logp = jax.nn.log_softmax(logits.astype(jnp.float32),
+                                          axis=-1)
+                ll = jnp.take_along_axis(logp, labels[..., None],
+                                         axis=-1)[..., 0]
+                loss = -(ll * mask).sum() / denom
         return loss + aux, {"ce": loss, "aux": aux}
 
     # ---- serving ----

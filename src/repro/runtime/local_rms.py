@@ -12,6 +12,8 @@ import threading
 import time
 from typing import Callable, List, Optional, Tuple
 
+from jax.profiler import TraceAnnotation
+
 from repro.core.actions import Action, Decision
 from repro.rms.cluster import Cluster
 from repro.rms.job import Job, JobState
@@ -69,11 +71,12 @@ class LocalRMS:
                          preferred: Optional[int]) -> Decision:
         with self._lock:
             job = next(j for j in self.jobs if j.job_id == job_id)
-            t0 = time.perf_counter()
-            decision = self.policy.decide(
-                self.cluster, self.pending(), job, minimum=minimum,
-                maximum=maximum, factor=factor, preferred=preferred)
-            elapsed = time.perf_counter() - t0
+            with TraceAnnotation("rms.decide", job=job_id, current=current):
+                t0 = time.perf_counter()
+                decision = self.policy.decide(
+                    self.cluster, self.pending(), job, minimum=minimum,
+                    maximum=maximum, factor=factor, preferred=preferred)
+                elapsed = time.perf_counter() - t0
             if decision.action is not Action.NO_ACTION:
                 self.cluster.resize(job_id, decision.new_slices)
                 job.nodes = decision.new_slices

@@ -14,14 +14,15 @@ from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, Optional
+from typing import Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import (DMR, TP_DP_RULES, Action, ShardingRules, make_mesh,
-                        mesh_num_slices, reshard, state_shardings)
+                        mesh_num_slices, moved_bytes, reshard)
 from repro.core.sharding import logical_to_sharding
 from repro.data import DataConfig, SyntheticLMData
 from repro.checkpoint.store import CheckpointStore
@@ -66,6 +67,8 @@ class ElasticTrainer:
             if rms is not None else None
         self.store = CheckpointStore(cfg.ckpt_dir) if cfg.ckpt_dir else None
         self._step_cache: Dict[int, Callable] = {}
+        self._stepped: set = set()     # step functions called at least once
+        self._moved: Dict[Tuple[int, int], int] = {}   # (from, to) -> bytes
         self.metrics: list = []
         self.resize_log: list = []
 
@@ -161,8 +164,9 @@ class ElasticTrainer:
             else:
                 (loss, _parts), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(state["params"], batch)
-            params, opt, metrics = apply_updates(
-                opt_cfg, state["params"], grads, state["opt"])
+            with jax.named_scope("adamw"):
+                params, opt, metrics = apply_updates(
+                    opt_cfg, state["params"], grads, state["opt"])
             new_state = {"params": params, "opt": opt,
                          "rng": jax.random.fold_in(state["rng"], 0),
                          "step": state["step"] + 1}
@@ -190,19 +194,33 @@ class ElasticTrainer:
             factor=self.cfg.factor, preferred=self.cfg.preferred)
         if action is Action.NO_ACTION:
             return state
+        # the step is on the enclosing ``train.reconfigure`` span
+        span = {"action": action.name, "from": self.slices, "to": new_slices}
+        layouts = (self.slices, new_slices)
         t0 = time.perf_counter()
-        new_mesh = make_mesh(new_slices, self.cfg.model_ways,
-                             devices=self.devices)
-        new_shardings = self._state_shardings(new_mesh)
-        state = reshard(state, new_shardings)
-        jax.block_until_ready(state)
-        dt = time.perf_counter() - t0
+        with TraceAnnotation("reshard.plan", **span):
+            new_mesh = make_mesh(new_slices, self.cfg.model_ways,
+                                 devices=self.devices)
+            new_shardings = self._state_shardings(new_mesh)
+            if layouts not in self._moved:
+                self._moved[layouts] = moved_bytes(state, new_shardings)
+        t1 = time.perf_counter()
+        # the wait for the steps queued before this point stays inside the
+        # transfer: on TPU v5e, with the profiler on, a state drained first
+        # moved about three times faster than untraced, so a traced resize
+        # would not be the one an untraced job gets
+        with TraceAnnotation("reshard.transfer", **span):
+            state = reshard(state, new_shardings)
+            jax.block_until_ready(state)
+        t2 = time.perf_counter()
         if handler is not None:
             handler.new_mesh = new_mesh
-            handler.resize_time_s = dt
+            handler.resize_time_s = t2 - t0
+            handler.transfer_s = t2 - t1
+            handler.moved_bytes = self._moved[layouts]
         self.resize_log.append(
             {"step": int(state["step"]), "action": action.name,
-             "from": self.slices, "to": new_slices, "resize_s": dt})
+             "from": self.slices, "to": new_slices, "resize_s": t2 - t0})
         self.mesh = new_mesh
         self.slices = new_slices
         return state
@@ -223,11 +241,16 @@ class ElasticTrainer:
                 on_step(step)
             if self.dmr is not None and step > start and \
                     step % self.cfg.check_period == 0:
-                state = self.maybe_reconfigure(state)
-            batch = self.data.batch(step)
+                # the old layout's buffers are released at the rebinding
+                with TraceAnnotation("train.reconfigure", step=step):
+                    state = self.maybe_reconfigure(state)
+            with TraceAnnotation("train.batch", step=step):
+                batch = self.data.batch(step)
             fn = self.step_fn(self.mesh)
+            # a step function's first call traces and compiles it
+            name = "train.step" if fn in self._stepped else "train.compile"
             try:
-                with self.mesh:
+                with TraceAnnotation(name, step=step), self.mesh:
                     state, metrics = fn(state, batch)
             except jax.errors.JaxRuntimeError:
                 restart = self._restart_step()
@@ -235,17 +258,21 @@ class ElasticTrainer:
                                        and step <= failed_at):
                     raise
                 failed_at = step
-                state = self.restore(restart)
+                with TraceAnnotation("train.restore", step=restart):
+                    state = self.restore(restart)
                 step = restart
                 continue
+            self._stepped.add(fn)
             step += 1
             if step % self.cfg.log_period == 0 or step == self.cfg.steps:
-                m = {k: float(v) for k, v in metrics.items()}
+                with TraceAnnotation("train.log_sync", step=step):
+                    m = {k: float(v) for k, v in metrics.items()}
                 m["step"] = step
                 m["slices"] = self.slices
                 self.metrics.append(m)
             if self.store is not None and step % self.cfg.ckpt_period == 0:
-                self.store.save_async(step, state)
+                with TraceAnnotation("train.save", step=step):
+                    self.store.save_async(step, state)
         if self.store is not None:
             self.store.wait()
         return state
