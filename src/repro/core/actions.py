@@ -42,6 +42,7 @@ class ResizeHandler:
     # ... of which the transfer, with the wait for the queued steps:
     transfer_s: float = 0.0
     moved_bytes: int = 0                   # bytes the new layout brought
+    host_leaves: int = 0                   # state leaves sent through host
     timed_out: bool = False
 
     @property

@@ -21,8 +21,9 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import (DMR, TP_DP_RULES, Action, ShardingRules, make_mesh,
-                        mesh_num_slices, moved_bytes, reshard)
+from repro.core import (DMR, TP_DP_RULES, Action, ReshardPlan, ShardingRules,
+                        make_mesh, mesh_num_slices, moved_bytes, plan_reshard,
+                        reshard)
 from repro.core.sharding import logical_to_sharding
 from repro.data import DataConfig, SyntheticLMData
 from repro.checkpoint.store import CheckpointStore
@@ -68,7 +69,8 @@ class ElasticTrainer:
         self.store = CheckpointStore(cfg.ckpt_dir) if cfg.ckpt_dir else None
         self._step_cache: Dict[int, Callable] = {}
         self._stepped: set = set()     # step functions called at least once
-        self._moved: Dict[Tuple[int, int], int] = {}   # (from, to) -> bytes
+        # (from, to) -> the bytes a resize moves, and how it moves them
+        self._plans: Dict[Tuple[int, int], Tuple[int, ReshardPlan]] = {}
         self.metrics: list = []
         self.resize_log: list = []
 
@@ -202,25 +204,29 @@ class ElasticTrainer:
             new_mesh = make_mesh(new_slices, self.cfg.model_ways,
                                  devices=self.devices)
             new_shardings = self._state_shardings(new_mesh)
-            if layouts not in self._moved:
-                self._moved[layouts] = moved_bytes(state, new_shardings)
+            if layouts not in self._plans:
+                self._plans[layouts] = (moved_bytes(state, new_shardings),
+                                        plan_reshard(state, new_shardings))
+            moved, plan = self._plans[layouts]
         t1 = time.perf_counter()
         # the wait for the steps queued before this point stays inside the
         # transfer: on TPU v5e, with the profiler on, a state drained first
         # moved about three times faster than untraced, so a traced resize
         # would not be the one an untraced job gets
         with TraceAnnotation("reshard.transfer", **span):
-            state = reshard(state, new_shardings)
+            state = reshard(state, new_shardings, plan=plan, span=span)
             jax.block_until_ready(state)
         t2 = time.perf_counter()
         if handler is not None:
             handler.new_mesh = new_mesh
             handler.resize_time_s = t2 - t0
             handler.transfer_s = t2 - t1
-            handler.moved_bytes = self._moved[layouts]
+            handler.moved_bytes = moved
+            handler.host_leaves = plan.host_leaves
         self.resize_log.append(
             {"step": int(state["step"]), "action": action.name,
-             "from": self.slices, "to": new_slices, "resize_s": t2 - t0})
+             "from": self.slices, "to": new_slices, "resize_s": t2 - t0,
+             "host_leaves": plan.host_leaves})
         self.mesh = new_mesh
         self.slices = new_slices
         return state

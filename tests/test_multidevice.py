@@ -196,3 +196,131 @@ def test_launcher_elastic_job_shrinks_and_expands_under_a_rival():
     assert out["resizes"] == [["SHRINK", 4], ["EXPAND", 8]]
     assert out["spans"] == [8]
     assert len(out["losses"]) == 6
+
+
+# A full ZeRO-1 TrainState of the reduced SmolLM on ``big`` slices, and its
+# shardings on ``big`` and ``small`` slices of the same device prefix.
+ZERO1_STATE = """
+import sys
+import jax._src.array as jax_array
+from repro.core import crosses_host, make_mesh, plan_reshard, reshard
+from repro.launch.train import build
+
+big, small = {big}, {small}
+trainer, _ = build("smollm-135m", reduced=True, seq_len=32, global_batch=8,
+                   slices=big)
+state = trainer.init_state(0)
+lay = {{n: trainer._state_shardings(make_mesh(n, 1, devices=trainer.devices))
+       for n in (big, small)}}
+
+host_pulls = []        # each array JAX fetched to host memory to re-place
+value = jax_array.ArrayImpl._value
+jax_array.ArrayImpl._value = property(
+    lambda self: host_pulls.append(1) or value.fget(self))
+
+def pulls(fn, *args, **kw):
+    host_pulls.clear()
+    out = jax.block_until_ready(fn(*args, **kw))
+    return out, len(host_pulls)
+"""
+
+
+@pytest.mark.parametrize("big,small", [(4, 2), (8, 2)])
+def test_zero1_state_reshard_is_bit_exact_and_stays_on_the_devices(big, small):
+    """Resized big -> small -> big, the bridged reshard gives every leaf the
+    shards ``jax.device_put`` gives it, bit for bit, on the same devices;
+    the plain device_put sends each split moment through host memory, the
+    bridged path none."""
+    out = run_sub(ZERO1_STATE.format(big=big, small=small) + """
+def same(a, b):
+    return a.sharding == b.sharding and all(
+        sa.device == sb.device and sa.index == sb.index
+        and np.asarray(sa.data).tobytes() == np.asarray(sb.data).tobytes()
+        for sa, sb in zip(a.addressable_shards, b.addressable_shards))
+
+split = sum(any(ax is not None for ax in s.spec)
+            for s in jax.tree.leaves(lay[small]["opt"]))
+res = {"split_moments": split}
+x = state
+for step, b in enumerate((small, big)):
+    plan = plan_reshard(x, lay[b])
+    plain, plain_pulls = pulls(jax.device_put, x, lay[b])
+    y, bridged_pulls = pulls(reshard, x, lay[b], plan=plan)
+    res[step] = {
+        "same": all(jax.tree.leaves(jax.tree.map(same, y, plain))),
+        "plain_rule": sum(crosses_host(l.sharding, s, l.shape)
+                           for l, s in zip(jax.tree.leaves(x),
+                                           jax.tree.leaves(lay[b]))),
+        "bridged": sum(p is not None for p in plan.bridges),
+        "host_leaves": plan.host_leaves, "note": plan.note,
+        "plain_pulls": plain_pulls, "bridged_pulls": bridged_pulls}
+    x = y
+res["back"] = all(jax.tree.leaves(jax.tree.map(
+    lambda a, b: np.asarray(a).tobytes() == np.asarray(b).tobytes(),
+    x, state)))
+print(json.dumps(res))
+""")
+    assert out["split_moments"] > 0 and out["back"]
+    for step in ("0", "1"):
+        r = out[step]
+        assert r["same"]
+        assert r["plain_rule"] == r["plain_pulls"] == out["split_moments"]
+        assert r["bridged"] == out["split_moments"]
+        assert r["host_leaves"] == r["bridged_pulls"] == 0
+        assert r["note"] == ""
+
+
+def test_a_second_resize_of_a_pair_does_not_retrace_the_relayouts():
+    out = run_sub(ZERO1_STATE.format(big=4, small=2) + """
+traces = []
+jax.monitoring.register_event_duration_secs_listener(
+    lambda name, secs, **kw: traces.append(1)
+    if name == "/jax/core/compile/jaxpr_trace_duration" else None)
+
+plans = {(4, 2): plan_reshard(state, lay[2])}
+x = reshard(state, lay[2], plan=plans[(4, 2)])
+plans[(2, 4)] = plan_reshard(x, lay[4])
+x = jax.block_until_ready(reshard(x, lay[4], plan=plans[(2, 4)]))
+first = len(traces)
+for _ in range(2):
+    x = reshard(x, lay[2], plan=plans[(4, 2)])
+    x = jax.block_until_ready(reshard(x, lay[4], plan=plans[(2, 4)]))
+again = len(traces) - first
+x = reshard(x, lay[2])            # a plan made anew finds the same jit
+x = jax.block_until_ready(reshard(x, lay[4]))
+print(json.dumps({"first": first, "again": again,
+                  "fresh_plan": len(traces) - first - again}))
+""")
+    assert out["first"] == 2           # one relayout each way
+    assert out["again"] == 0 and out["fresh_plan"] == 0
+
+
+def test_elastic_losses_are_the_same_bridged_and_plain():
+    """An elastic job that shrinks 8 -> 4 and expands back computes the same
+    losses, and ends on the same bits, whether its reshard bridges the
+    moments or sends them through host memory."""
+    out = run_sub("""
+    import repro.runtime.trainer as trainer_mod
+    from repro.launch.train import build
+    from repro.runtime import scripted_rival
+
+    def run():
+        trainer, rms = build("smollm-135m", reduced=True, seq_len=32,
+                             global_batch=8, steps=8, slices=8, elastic=True,
+                             check_period=2)
+        state = trainer.train(on_step=scripted_rival(rms, submit_at=2,
+                                                     finish_at=4))
+        return ([m["loss"] for m in trainer.metrics],
+                [(r["action"], r["host_leaves"]) for r in trainer.resize_log],
+                [np.asarray(x).tobytes() for x in jax.tree.leaves(state)])
+
+    bridged = run()
+    trainer_mod.reshard = lambda state, shardings, **kw: jax.device_put(
+        state, shardings)
+    plain = run()
+    print(json.dumps({"losses": bridged[0] == plain[0],
+                      "bits": bridged[2] == plain[2],
+                      "resizes": bridged[1], "n": len(bridged[0])}))
+    """)
+    assert out["resizes"] == [["SHRINK", 0], ["EXPAND", 0]]
+    assert out["n"] == 8 and out["losses"] and out["bits"]

@@ -23,7 +23,8 @@ BENCH = os.path.join(REPO, "benchmarks", "chip")
 PROGRAM_SPANS = {
     "train.reconfigure", "train.batch", "train.step", "train.compile",
     "train.log_sync", "train.save", "train.restore", "reshard.plan",
-    "reshard.transfer", "dmr.query", "dmr.expand_wait", "rms.decide"}
+    "reshard.transfer", "reshard.relayout", "dmr.query", "dmr.expand_wait",
+    "rms.decide"}
 
 CYCLE = """
 import glob, json, tempfile
@@ -109,6 +110,9 @@ print(json.dumps({
                   h.resize_time_s, h.transfer_s, h.moved_bytes]
                  for h in trainer.dmr.history
                  if h.action.name in ("EXPAND", "SHRINK")],
+    "host_leaves": [[h.host_leaves for h in trainer.dmr.history
+                     if h.action.name in ("EXPAND", "SHRINK")],
+                    [r["host_leaves"] for r in trainer.resize_log]],
     "hand": hand, "counted": len(counted),
     "params_replicated": params_replicated,
     "params_on_shrink": params_on_shrink,
@@ -177,6 +181,20 @@ def test_reshard_spans_lie_in_order_inside_the_reconfiguration(cycle):
         assert (plan[3]["action"], plan[3]["from"], plan[3]["to"]) in (
             ("SHRINK", "4", "2"), ("EXPAND", "2", "4"))
     assert steps == [2, 4, 6, 8]
+
+
+def test_each_transfer_holds_one_relayout_with_its_labels(cycle):
+    transfers = _spans(cycle, "reshard.transfer")
+    relayouts = _spans(cycle, "reshard.relayout")
+    assert len(relayouts) == len(transfers) == 4
+    for relayout, transfer in zip(relayouts, transfers):
+        assert _inside(relayout, transfer)
+        assert relayout[3] == transfer[3]
+
+
+def test_no_state_leaf_crosses_host_memory(cycle):
+    handlers, log = cycle["host_leaves"]
+    assert handlers == log == [0, 0, 0, 0]
 
 
 def test_the_rms_decision_lies_inside_the_dmr_query(cycle):
