@@ -13,6 +13,15 @@ outputs, temporaries, the sum against the chip's 16 GB) and how many
 all-reduce, reduce-scatter and all-gather operations it holds.  Nothing
 runs: this says what the chip's compiler accepts and what it allocates,
 not how fast anything is.
+
+Beside each cell's step it compiles, for the first chip, the set-up's
+change of the weights (``harness.delta_norm_fn``, which runs beside the
+program's state) and the reference's gradient and update
+(``references/train.py``), which run after the program's state is freed.
+Their rows give, besides what each program holds, ``phase_bytes``: that
+and the trees that live beside it (the two moments, beside the gradient),
+and ``param_copy_bytes``, one float32 copy of the parameters, the unit of
+the harness's budget.
 """
 from __future__ import annotations
 
@@ -84,6 +93,57 @@ def rehearse(cell, topo) -> list:
     return out
 
 
+def rehearse_harness(cell, device) -> list:
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    import harness
+    import traffic
+    from references import train as ref_train
+
+    ref, cfg, mix = cell.reference, cell.config, cell.mix
+    one = SingleDeviceSharding(device)
+
+    def placed(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+    key = placed(jax.eval_shape(
+        lambda: traffic.seed_key(0, traffic.WEIGHTS)))
+    params = jax.eval_shape(lambda k: ref.init_params(k, cfg), key)
+    copy = sum(4 * x.size for x in jax.tree.leaves(params))
+    params = placed(params)
+    prog = placed(jax.eval_shape(lambda k: ref.to_program(
+        ref.init_params(k, cfg), cfg), key))
+    tokens = jax.ShapeDtypeStruct((mix["global_batch"], mix["seq_len"]),
+                                  jnp.int32, sharding=one)
+    scalar = jax.ShapeDtypeStruct((), jnp.float32, sharding=one)
+    decay = [ref_train.decays(n, x.shape) for n, x in
+             zip(ref_train.leaf_names(params), jax.tree.leaves(params))]
+    parts = [
+        ("setup.delta_norm", 0,
+         harness.delta_norm_fn(cell).lower(prog, key)),
+        ("reference.gradient", 2 * copy,
+         ref_train.make_grad_fn(ref, cfg).lower(params, tokens, tokens,
+                                                params)),
+        ("reference.update", 0,
+         ref_train.make_update(mix["optimizer"], decay).lower(
+             params, params, params, params, scalar, scalar)),
+    ]
+    out = []
+    for part, beside, lowered in parts:
+        ma = lowered.compile().memory_analysis()
+        held = harness.held_bytes(ma)
+        out.append({"workload": cell.name, "part": part,
+                    "argument_bytes": ma.argument_size_in_bytes,
+                    "output_bytes": ma.output_size_in_bytes,
+                    "alias_bytes": ma.alias_size_in_bytes,
+                    "temp_bytes": ma.temp_size_in_bytes,
+                    "total_bytes": held, "phase_bytes": held + beside,
+                    "param_copy_bytes": copy})
+    return out
+
+
 def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     ap.add_argument("--workload", action="append")
@@ -98,7 +158,9 @@ def main(argv=None) -> int:
     names = args.workload or [w["name"]
                               for w in harness.benchmark()["workloads"]]
     for name in names:
-        for row in rehearse(harness.load_cell(name), topo):
+        cell = harness.load_cell(name)
+        for row in rehearse(cell, topo) + rehearse_harness(
+                cell, topo.devices[0]):
             print(json.dumps(row), flush=True)
     return 0
 

@@ -18,6 +18,27 @@ window drives ``ElasticTrainer.train`` in segments, each ended by a wait for
 the returned state.  The benchmark replaces the trainer's feed with its own
 (``traffic.TokenFeed``), makes the weights itself from the seed in one
 jitted call, and wraps the trainer's bound callables in trace annotations.
+
+Adding a configuration takes new files and appended entries alone:
+
+- files: ``configs/<config>.json`` (with its ``cpu_test`` sizes, which the
+  CPU tests read), ``references/<family>.py`` where no reference computes
+  it yet, ``mixes/<traffic>.json``, ``limits/<workload>.json`` and, for a
+  new per-layer metric, ``layer_metrics/<metric>.py``;
+- entries appended to ``BENCHMARK.json``: one under ``configs``, one under
+  ``workloads`` for each cell, and the cell's name in the ``workloads``
+  list of each ``per_layer`` metric it reports, or a new such metric.
+
+Memory, in float32 copies of the parameters on the cell's first chip:
+the program holds its own state and step (16 bytes a parameter with fp32
+weights, gradients and the two AdamW moments).  Set-up adds at most one
+copy beside it: ``make_state`` holds its outputs alone, and
+``delta_norm_fn`` makes the start again leaf by leaf inside the norms'
+fusions.  The reference runs once the program's state is freed and holds
+at most five copies (20 bytes a parameter) and one row's activations:
+the parameters, the two moments, the gradient, and one row's gradient
+while the gradient is summed.  ``compile_rehearsal.py`` prints each of
+these for a described v5e before any chip run.
 """
 from __future__ import annotations
 

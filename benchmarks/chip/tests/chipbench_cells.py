@@ -2,8 +2,9 @@
 
 Each cell is loaded from the benchmark's own files by name and then cut to
 what ``repro.models.reduced_config`` builds (``build(..., reduced=True)``):
-the configuration's sizes, the program fields the harness checks, and the
-mix's sequence length and batch.  Nothing else changes.
+the configuration's sizes and the program values the harness checks, as its
+file's ``cpu_test`` key gives them, and the mix's sequence length and
+batch.  Nothing else changes.
 """
 from __future__ import annotations
 
@@ -21,15 +22,11 @@ for p in (BENCH, os.path.join(ROOT, "src")):
 
 import harness  # noqa: E402
 
-REDUCED = {
-    "smollm-135m": ({"hidden_size": 128, "intermediate_size": 256,
-                     "num_hidden_layers": 2, "num_attention_heads": 4,
-                     "num_key_value_heads": 1, "vocab_size": 2048},
-                    {"head_dim": 32}),
-    "mamba2-130m": ({"d_model": 128, "n_layer": 2, "vocab_size": 2048,
-                     "vocab_rows": 2048, "d_state": 16, "headdim": 16,
-                     "chunk_size": 16}, {}),
-}
+
+def configs() -> list:
+    """The name of every configuration file under ``configs/``."""
+    return sorted(f[:-len(".json")] for f in os.listdir(
+        os.path.join(BENCH, "configs")) if f.endswith(".json"))
 
 
 def reduced_cell(name: str, seq_len: int = 32, batch: int = 4
@@ -52,9 +49,9 @@ def reduce(cell: "harness.Cell", seq_len: int = 32, batch: int = 4
            ) -> "harness.Cell":
     cell = dataclasses.replace(cell, config=copy.deepcopy(cell.config),
                                mix=copy.deepcopy(cell.mix))
-    sizes, values = REDUCED[cell.config["name"]]
-    cell.config.update(sizes)
-    cell.config["program"]["values"].update(values)
+    cut = cell.config["cpu_test"]
+    cell.config.update(cut["sizes"])
+    cell.config["program"]["values"].update(cut["values"])
     cell.mix.update(seq_len=seq_len, global_batch=batch)
     if cell.elastic:
         cell.mix.update(check_period=2, segment_steps=4,

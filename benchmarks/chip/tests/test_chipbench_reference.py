@@ -20,7 +20,8 @@ import chipbench_cells  # noqa: E402
 import harness  # noqa: E402
 from references import train as ref_train  # noqa: E402
 
-CASES = [("smollm-135m", "smollm-135m"), ("mamba2-130m", "mamba2-130m")]
+CASES = [(c, harness.load_json(f"configs/{c}.json")["program"]["arch"])
+         for c in chipbench_cells.configs()]
 
 
 def reduced(config: str):
@@ -47,7 +48,8 @@ def test_reference_matches_program_model(config, arch):
         (loss_p, _), g_p = jax.value_and_grad(model.loss, has_aux=True)(
             ref.to_program(params, cfg), batch)
         loss_r, g_r = ref_train.make_grad_fn(ref, cfg)(
-            params, batch["tokens"], batch["labels"])
+            params, batch["tokens"], batch["labels"],
+            ref_train.zeros(params))
     assert float(loss_p) == pytest.approx(float(loss_r), rel=1e-5)
     got = ref_train.unit_norms(ref.from_program(g_p, cfg))
     want = ref_train.unit_norms(g_r)
