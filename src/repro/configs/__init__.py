@@ -1,14 +1,15 @@
 """Architecture configs (assigned pool) + lookup by --arch id."""
-from repro.configs import (deepseek_moe_16b, gemma2_27b, granite_3_2b,
-                           mamba2_130m, paligemma_3b, phi35_moe_42b,
-                           qwen3_4b, recurrentgemma_9b, seamless_m4t_medium,
-                           smollm_135m)
+from repro.configs import (deepseek_moe_16b, deepseek_v2_lite, gemma2_27b,
+                           granite_3_2b, mamba2_130m, paligemma_3b,
+                           phi35_moe_42b, qwen3_4b, recurrentgemma_9b,
+                           seamless_m4t_medium, smollm_135m)
 
 ARCHS = {
     m.CONFIG.name: m.CONFIG
     for m in (smollm_135m, granite_3_2b, qwen3_4b, gemma2_27b,
               recurrentgemma_9b, deepseek_moe_16b, phi35_moe_42b,
-              seamless_m4t_medium, mamba2_130m, paligemma_3b)
+              seamless_m4t_medium, mamba2_130m, paligemma_3b,
+              deepseek_v2_lite)
 }
 
 # short aliases for --arch

@@ -28,6 +28,7 @@ from jax.sharding import NamedSharding
 
 from repro.models import encdec as ed
 from repro.models import transformer as tf
+from repro.models.moe import zero_stats as moe_zero_stats
 from repro.core.sharding import activation_rules
 from repro.models.layers import abstract_params, is_spec, logical_tree
 
@@ -94,12 +95,11 @@ def lm_loops(model, cfg, shape, mesh, rules, kind: str, accum: int = 1):
                                               x_abs.shape, mesh))
 
     if kind == "train":
-        def fwd(up, x):
-            aux = jnp.zeros((), jnp.float32)
-            for j, kj in enumerate(cfg.pattern):
-                x, aux = tf.block_apply(up[f"p{j}"], x, cfg, kj, aux)
-            return x.astype(jnp.float32).sum() + aux
-        fwd_ck = _remat(fwd, cfg)
+        unit = model.scan_unit()
+
+        def fwd_ck(up, x):
+            (x, stats), _ = unit((x, moe_zero_stats(cfg)), up)
+            return x.astype(jnp.float32).sum() + stats["aux"]
 
         def unit_flops(up, x):
             return jax.grad(fwd_ck, argnums=(0, 1))(up, x)

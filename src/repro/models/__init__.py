@@ -1,4 +1,4 @@
-"""Model zoo: the 10 assigned architectures over shared substrate layers."""
+"""Model zoo: the assigned architectures over shared substrate layers."""
 from repro.models.config import ModelConfig
 from repro.models.registry import build_model, get_model, list_archs, reduced_config
 from repro.models.transformer import CausalLM

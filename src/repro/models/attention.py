@@ -1,5 +1,6 @@
-"""Attention: GQA projections, exact-FLOPs chunked causal attention (XLA
-path), local sliding windows, logit softcaps, qk-norm, and KV caches.
+"""Attention: GQA projections, multi-head latent attention (MLA), exact-FLOPs
+chunked causal attention (XLA path), local sliding windows, logit softcaps,
+qk-norm, and KV caches.
 
 Implementation notes (TPU adaptation):
 
@@ -23,7 +24,8 @@ from typing import Any, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro.models.layers import ParamSpec, apply_rope, rms_norm, softcap
+from repro.models.layers import (ParamSpec, apply_rope, rms_norm, softcap,
+                                 yarn_mscale)
 
 NEG_INF = -2.0 ** 30
 
@@ -62,13 +64,13 @@ def _project_qkv(params, x, cfg, positions, rope: bool = True,
     return q, k, v
 
 
-def _sdpa_chunk(q, k, v, mask, cfg, state=None):
+def _sdpa_chunk(q, k, v, mask, cfg, scale, state=None):
     """Online-softmax update of one (q-chunk, kv-chunk) pair.
 
-    q: (B, Sq, KV, G, D); k/v: (B, Sk, KV, D); mask: (Sq, Sk) or None.
-    state: (m, l, acc) running max / normalizer / weighted accumulator.
+    q: (B, Sq, KV, G, D); k: (B, Sk, KV, D); v: (B, Sk, KV, Dv);
+    mask: (Sq, Sk) or None.  state: (m, l, acc) running max / normalizer /
+    weighted accumulator.
     """
-    scale = 1.0 / math.sqrt(q.shape[-1])
     logits = jnp.einsum("bqkgd,bskd->bkgqs", q, k).astype(jnp.float32) * scale
     logits = softcap(logits, cfg.attn_logit_softcap)
     if mask is not None:
@@ -91,15 +93,20 @@ def _finish(l, acc):
     return (acc / jnp.maximum(l, 1e-30)[..., None].astype(acc.dtype))
 
 
-def chunked_attention(q, k, v, cfg, *, causal: bool, window: Optional[int]):
+def chunked_attention(q, k, v, cfg, *, causal: bool, window: Optional[int],
+                      scale: Optional[float] = None):
     """Exact-FLOPs chunked attention.
 
-    q: (B, S, H, D) -> grouped (B, S, KV, G, D).  The query axis is python-
-    unrolled in chunks; each chunk attends a *static* key slice (its causal
-    prefix, or its sliding window), with an inner scan over kv chunks
-    carrying the online-softmax state.
+    q: (B, S, H, D) -> grouped (B, S, KV, G, D); k: (B, S, KV, D); v: (B,
+    S, KV, Dv), whose width may differ from the query's.  Logits are scaled
+    by ``scale`` (default 1/sqrt(D)).  The query axis is python-unrolled in
+    chunks; each chunk attends a *static* key slice (its causal prefix, or
+    its sliding window), with an inner scan over kv chunks carrying the
+    online-softmax state.
     """
     b, s, h, d = q.shape
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     kvh = k.shape[2]
     g = h // kvh
     q = q.reshape(b, s, kvh, g, d)
@@ -137,7 +144,7 @@ def chunked_attention(q, k, v, cfg, *, causal: bool, window: Optional[int]):
                     mask &= q_pos[:, None] >= kv_pos[None, :]
                 if window is not None:
                     mask &= q_pos[:, None] - kv_pos[None, :] < window
-            m, l, acc = _sdpa_chunk(q_i, kk, vv, mask, cfg, state)
+            m, l, acc = _sdpa_chunk(q_i, kk, vv, mask, cfg, scale, state)
             state = (m, l, acc)
         return _finish(state[1], state[2])
 
@@ -160,8 +167,8 @@ def chunked_attention(q, k, v, cfg, *, causal: bool, window: Optional[int]):
         fn = one_chunk_ckpt if n_chunks > 1 else one_chunk
         outs.append(fn(q_i, k[:, lo:hi], v[:, lo:hi], i, lo, hi))
     out = jnp.concatenate(outs, axis=-2) if len(outs) > 1 else outs[0]
-    # (B,KV,G,S,D) -> (B,S,H,D)
-    out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, d)
+    # (B,KV,G,S,Dv) -> (B,S,H,Dv)
+    out = out.transpose(0, 3, 1, 2, 4).reshape(b, s, h, v.shape[-1])
     return out
 
 
@@ -269,3 +276,133 @@ def attention_prefill(params, x, cfg, *, kind: str = "global",
         pos = jnp.concatenate([jnp.arange(s, dtype=jnp.int32),
                                jnp.full((pad,), -1, jnp.int32)])
     return y, {"k": k_keep, "v": v_keep, "pos": pos}
+
+
+# -- multi-head latent attention (DeepSeek-V2) ------------------------------------
+
+
+def mla_specs(cfg) -> Dict[str, Any]:
+    """HF ``DeepseekV2Attention`` without a query latent (``q_lora_rank``
+    null): ``wq`` is ``q_proj``, ``wkv_a`` ``kv_a_proj_with_mqa`` (the latent
+    and the shared rotary key), ``kv_norm`` ``kv_a_layernorm`` (an offset
+    from 1), ``wkv_b`` ``kv_b_proj`` (each head's key part and value), ``wo``
+    ``o_proj``."""
+    e, h, r = cfg.d_model, cfg.num_heads, cfg.kv_lora_rank
+    nope, rope, vd = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    return {
+        "wq": ParamSpec((e, h, nope + rope), ("embed", "heads", "head_dim")),
+        "wkv_a": ParamSpec((e, r + rope), ("embed", "kv_latent")),
+        "kv_norm": ParamSpec((r,), ("kv_latent",), init="zeros"),
+        "wkv_b": ParamSpec((r, h, nope + vd),
+                           ("kv_latent", "heads", "head_dim")),
+        "wo": ParamSpec((h, vd, e), ("heads", "head_dim", "embed")),
+    }
+
+
+def mla_scale(cfg) -> float:
+    """Softmax scale: 1/sqrt(query-key width), times YaRN's mscale of
+    ``mscale_all_dim`` squared where the configuration sets it."""
+    scale = 1.0 / math.sqrt(cfg.qk_nope_head_dim + cfg.qk_rope_head_dim)
+    ys = cfg.rope_scaling
+    if ys and ys.get("mscale_all_dim"):
+        m = yarn_mscale(ys["factor"], ys["mscale_all_dim"])
+        scale *= m * m
+    return scale
+
+
+def _mla_rope(x, positions, cfg):
+    return apply_rope(x, positions, cfg.rope_theta, cfg.rope_scaling,
+                      interleaved=True)
+
+
+def _mla_query(params, x, cfg, positions):
+    """Per head: the query's plain part and its rotated part."""
+    q = jnp.einsum("bse,ehd->bshd", x, params["wq"].astype(x.dtype))
+    nope = cfg.qk_nope_head_dim
+    return q[..., :nope], _mla_rope(q[..., nope:], positions, cfg)
+
+
+def _mla_latent(params, x, cfg, positions):
+    """What the cache holds a token: the normalised latent (B, S, r) and
+    the rotated key shared by the heads (B, S, rope)."""
+    ckv = x @ params["wkv_a"].astype(x.dtype)
+    r = cfg.kv_lora_rank
+    c = rms_norm(ckv[..., :r], params["kv_norm"], cfg.norm_eps)
+    k_pe = _mla_rope(ckv[..., None, r:], positions, cfg)[:, :, 0]
+    return c, k_pe
+
+
+def _mla_forward(params, x, cfg):
+    """Full-sequence causal MLA: the output and the cache's two parts."""
+    b, s, _ = x.shape
+    h, nope = cfg.num_heads, cfg.qk_nope_head_dim
+    positions = jnp.broadcast_to(jnp.arange(s), (b, s))
+    q_nope, q_pe = _mla_query(params, x, cfg, positions)
+    c, k_pe = _mla_latent(params, x, cfg, positions)
+    kv = jnp.einsum("bsr,rhd->bshd", c, params["wkv_b"].astype(x.dtype))
+    k_pe_heads = jnp.broadcast_to(k_pe[:, :, None],
+                                  (b, s, h, cfg.qk_rope_head_dim))
+    q = jnp.concatenate([q_nope, q_pe], axis=-1)
+    k = jnp.concatenate([kv[..., :nope], k_pe_heads], axis=-1)
+    out = chunked_attention(q, k, kv[..., nope:], cfg, causal=True,
+                            window=None, scale=mla_scale(cfg))
+    y = jnp.einsum("bshd,hde->bse", out, params["wo"].astype(x.dtype))
+    return y, c, k_pe
+
+
+def mla_apply(params, x, cfg):
+    """Training path: x (B, S, E) -> (B, S, E)."""
+    return _mla_forward(params, x, cfg)[0]
+
+
+def mla_cache_specs(cfg, batch: int, length: int) -> Dict[str, Any]:
+    return {
+        "kv": ParamSpec((batch, length, cfg.kv_lora_rank),
+                        ("batch", "kv_seq", "kv_latent"), "zeros"),
+        "k_pe": ParamSpec((batch, length, cfg.qk_rope_head_dim),
+                          ("batch", "kv_seq", "head_dim"), "zeros"),
+        "pos": ParamSpec((length,), ("kv_seq",), "zeros"),
+    }
+
+
+def mla_prefill(params, x, cfg, *, cache_len: int):
+    """Full-sequence MLA that also returns the latent cache, padded to
+    ``cache_len``."""
+    s = x.shape[1]
+    y, c, k_pe = _mla_forward(params, x, cfg)
+    pad = ((0, 0), (0, cache_len - s), (0, 0))
+    pos = jnp.concatenate([jnp.arange(s, dtype=jnp.int32),
+                           jnp.full((cache_len - s,), -1, jnp.int32)])
+    return y, {"kv": jnp.pad(c, pad), "k_pe": jnp.pad(k_pe, pad), "pos": pos}
+
+
+def mla_decode(params, x, cfg, cache, pos):
+    """One-token decode through the latent cache.  The key and value
+    up-projection is absorbed: each head's query is mapped into the latent,
+    scored against the cached latents and shared rotary keys, and the
+    latent mix is mapped to the head's value afterwards.  x: (B, 1, E)."""
+    b, dt = x.shape[0], x.dtype
+    nope = cfg.qk_nope_head_dim
+    positions = jnp.full((b, 1), pos, jnp.int32)
+    q_nope, q_pe = _mla_query(params, x, cfg, positions)
+    c, k_pe = _mla_latent(params, x, cfg, positions)
+    slot = pos % cache["kv"].shape[1]
+    new_cache = {
+        "kv": jax.lax.dynamic_update_slice_in_dim(cache["kv"], c, slot, 1),
+        "k_pe": jax.lax.dynamic_update_slice_in_dim(cache["k_pe"], k_pe,
+                                                    slot, 1),
+        "pos": jax.lax.dynamic_update_slice_in_dim(
+            cache["pos"], jnp.full((1,), pos, jnp.int32), slot, 0)}
+    wkv_b = params["wkv_b"].astype(dt)
+    q_lat = jnp.einsum("bqhd,rhd->bqhr", q_nope, wkv_b[..., :nope])
+    logits = (jnp.einsum("bqhr,bsr->bhqs", q_lat, new_cache["kv"])
+              + jnp.einsum("bqhd,bsd->bhqs", q_pe, new_cache["k_pe"]))
+    logits = logits.astype(jnp.float32) * mla_scale(cfg)
+    pos_arr = new_cache["pos"]
+    valid = (pos_arr >= 0) & (pos_arr <= pos)
+    logits = jnp.where(valid[None, None, None, :], logits, NEG_INF)
+    p = jax.nn.softmax(logits, axis=-1).astype(dt)
+    o_lat = jnp.einsum("bhqs,bsr->bqhr", p, new_cache["kv"])
+    out = jnp.einsum("bqhr,rhd->bqhd", o_lat, wkv_b[..., nope:])
+    y = jnp.einsum("bqhd,hde->bqe", out, params["wo"].astype(dt))
+    return y, new_cache

@@ -1,4 +1,4 @@
-"""Model configuration — one dataclass covering all 10 assigned families."""
+"""Model configuration — one dataclass covering every architecture family."""
 from __future__ import annotations
 
 import dataclasses
@@ -26,6 +26,18 @@ class ModelConfig:
     # ("rglru", "rglru", "local") for recurrentgemma, ("ssd",) for mamba2.
     pattern: Tuple[str, ...] = ("global",)
     rope_theta: float = 10000.0
+    # YaRN rope, the HF ``rope_scaling`` dict as published ("type",
+    # "factor", "original_max_position_embeddings", "beta_fast",
+    # "beta_slow", "mscale", "mscale_all_dim"); None: plain rope
+    rope_scaling: Optional[dict] = None
+
+    # multi-head latent attention (DeepSeek-V2); kv_lora_rank 0: not MLA.
+    # Keys and values come from one kv_lora_rank-wide latent and one
+    # qk_rope_head_dim-wide rotary key shared by the heads.
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
 
     # MoE
     num_experts: int = 0
@@ -34,8 +46,14 @@ class ModelConfig:
     expert_d_ff: int = 0
     first_dense_layers: int = 0
     first_dense_ff: int = 0
+    # experts this chip holds of each layer's num_experts (0: all); the
+    # router keeps all num_experts outputs
+    experts_held: int = 0
+    norm_topk_prob: bool = True        # renormalise the top-k weights
+    # balance loss: "switch" (over the batch) or "seq" (DeepSeek-V2's
+    # per-sequence loss)
+    router_aux: str = "switch"
     router_aux_coef: float = 0.01
-    capacity_factor: float = 1.25
 
     # SSM (mamba2 SSD)
     ssm_state: int = 0
@@ -62,7 +80,9 @@ class ModelConfig:
     embed_scale: bool = False          # gemma-style sqrt(d_model) scaling
     dtype: str = "bfloat16"
     param_dtype: str = "float32"
-    remat: str = "nothing_saveable"    # "none" | "nothing_saveable" | "dots"
+    # "none" | "nothing_saveable" | "dots" | "nested" (nothing_saveable,
+    # and each layer's attention rematerialised again in its own backward)
+    remat: str = "nothing_saveable"
     # attention implementation: "auto" picks pallas on TPU, chunked on CPU
     attn_impl: str = "auto"
     attn_chunk: int = 512
@@ -76,6 +96,14 @@ class ModelConfig:
                                self.d_model // self.num_heads)
 
     # -- derived -------------------------------------------------------------
+
+    @property
+    def mla(self) -> bool:
+        return self.kv_lora_rank > 0
+
+    @property
+    def held_experts(self) -> int:
+        return self.experts_held or self.num_experts
 
     @property
     def q_per_kv(self) -> int:
@@ -97,24 +125,30 @@ class ModelConfig:
         return self.num_layers // n, self.num_layers % n
 
     def param_count(self) -> int:
-        """Approximate parameter count (embeddings + blocks)."""
+        """Approximate parameter count (embeddings, blocks, final norm)."""
         e, h, kv, hd, f, v = (self.d_model, self.num_heads, self.num_kv_heads,
                               self.head_dim, self.d_ff, self.vocab_size)
         embed = v * e * (1 if self.tie_embeddings else 2)
-        total = embed
+        total = embed + e
         reps, tail = self.pattern_repeats
         counts = {}
         for kind in self.pattern:
             counts[kind] = counts.get(kind, 0) + reps
         for i, kind in enumerate(self.pattern[:tail]):
             counts[kind] = counts.get(kind, 0) + 1
+        if self.mla:
+            r, qk = self.kv_lora_rank, self.qk_nope_head_dim \
+                + self.qk_rope_head_dim
+            attn = (e * h * qk + e * (r + self.qk_rope_head_dim) + r
+                    + r * h * (self.qk_nope_head_dim + self.v_head_dim)
+                    + h * self.v_head_dim * e)
+        else:
+            attn = e * (h * hd + 2 * kv * hd) + h * hd * e
         for kind, n in counts.items():
             if kind in ("global", "local"):
-                attn = e * (h * hd + 2 * kv * hd) + h * hd * e
                 blk = attn + 3 * e * f + 2 * e
             elif kind == "moe":
-                attn = e * (h * hd + 2 * kv * hd) + h * hd * e
-                routed = self.num_experts * 3 * e * self.expert_d_ff
+                routed = self.held_experts * 3 * e * self.expert_d_ff
                 shared = self.num_shared_experts * 3 * e * self.expert_d_ff
                 blk = attn + routed + shared + e * self.num_experts + 2 * e
             elif kind == "ssd":
@@ -131,9 +165,12 @@ class ModelConfig:
                 blk = 0
             total += n * blk
         if self.family == "moe" and self.first_dense_layers:
-            # replace routed block ffn with a dense one for the first layers
+            # the first layers' feed-forward is a dense MLP, not experts
+            experts = (3 * e * self.expert_d_ff * (self.held_experts
+                                                   + self.num_shared_experts)
+                       + e * self.num_experts)
             total += self.first_dense_layers * (
-                3 * self.d_model * (self.first_dense_ff or self.d_ff))
+                3 * e * (self.first_dense_ff or self.d_ff) - experts)
         if self.enc_layers:
             attn = e * (h * hd + 2 * kv * hd) + h * hd * e
             total += self.enc_layers * (attn + 3 * e * f + 2 * e)
@@ -143,10 +180,12 @@ class ModelConfig:
         return total
 
     def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: top-k + shared only)."""
+        """Parameters touched per token (MoE: the held experts a token
+        chooses on average, and the shared ones)."""
         if self.family != "moe":
             return self.param_count()
         full = self.param_count()
-        inactive = (self.num_experts - self.top_k) * 3 * self.d_model \
-            * self.expert_d_ff * self.num_layers
-        return full - inactive
+        held = self.held_experts
+        inactive = (held - self.top_k * held / self.num_experts) * 3 \
+            * self.d_model * self.expert_d_ff * self.num_layers
+        return int(full - inactive)

@@ -98,18 +98,73 @@ def softcap(x, cap):
 # -- RoPE ------------------------------------------------------------------------
 
 
-def rope_frequencies(head_dim: int, theta: float):
-    return 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
-                                       dtype=jnp.float32) / head_dim))
+def yarn_mscale(factor: float, mscale: float) -> float:
+    """YaRN's attention scale for a context stretched ``factor`` times (HF
+    DeepSeek-V2 ``yarn_get_mscale``)."""
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
 
 
-def apply_rope(x, positions, theta: float = 10000.0):
-    """x: (..., seq, heads, head_dim); positions: (..., seq)."""
-    freqs = rope_frequencies(x.shape[-1], theta)
+def _yarn_correction_dim(rotations: float, dim: int, theta: float,
+                         original_len: int) -> float:
+    """The rotary dimension whose wavelength turns ``rotations`` times over
+    the original context."""
+    return (dim * math.log(original_len / (rotations * 2 * math.pi))
+            / (2 * math.log(theta)))
+
+
+def rope_frequencies(head_dim: int, theta: float, scaling=None):
+    """Inverse frequencies of the rotary pairs.  With YaRN ``scaling`` (the
+    HF ``rope_scaling`` dict) a linear ramp over the correction range of
+    ``beta_fast``..``beta_slow`` blends the plain frequency (fast pairs)
+    into the frequency divided by ``factor`` (slow pairs), as HF
+    ``DeepseekV2YarnRotaryEmbedding`` does."""
+    freqs = 1.0 / (theta ** (jnp.arange(0, head_dim, 2,
+                                        dtype=jnp.float32) / head_dim))
+    if scaling is None:
+        return freqs
+    orig = scaling["original_max_position_embeddings"]
+    low = max(math.floor(_yarn_correction_dim(
+        scaling["beta_fast"], head_dim, theta, orig)), 0)
+    high = min(math.ceil(_yarn_correction_dim(
+        scaling["beta_slow"], head_dim, theta, orig)), head_dim - 1)
+    if low == high:
+        high += 0.001
+    ramp = jnp.clip((jnp.arange(head_dim // 2, dtype=jnp.float32) - low)
+                    / (high - low), 0.0, 1.0)
+    inter = freqs / scaling["factor"]
+    return inter * ramp + freqs * (1.0 - ramp)
+
+
+def rope_mscale(scaling) -> float:
+    """The factor on YaRN's cos and sin: mscale over mscale_all_dim, each
+    through ``yarn_mscale``; 1 for plain rope."""
+    if scaling is None:
+        return 1.0
+    f = scaling["factor"]
+    return (yarn_mscale(f, scaling.get("mscale", 1.0))
+            / yarn_mscale(f, scaling.get("mscale_all_dim", 0.0)))
+
+
+def apply_rope(x, positions, theta: float = 10000.0, scaling=None,
+               interleaved: bool = False):
+    """x: (..., seq, heads, head_dim); positions: (..., seq).
+
+    ``interleaved``: the rotary pairs are adjacent dimensions (2i, 2i+1),
+    and the output holds the pairs' first members, then their second, as
+    HF DeepSeek-V2's ``apply_rotary_pos_emb`` de-interleaves them before
+    ``rotate_half``; otherwise pair i is (i, i + head_dim / 2)."""
+    freqs = rope_frequencies(x.shape[-1], theta, scaling)
     angles = positions[..., :, None].astype(jnp.float32) * freqs  # (..., S, hd/2)
     angles = angles[..., None, :]                                 # (..., S, 1, hd/2)
     cos, sin = jnp.cos(angles), jnp.sin(angles)
-    x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
+    mscale = rope_mscale(scaling)
+    if mscale != 1.0:
+        cos, sin = cos * mscale, sin * mscale
+    if interleaved:
+        pairs = x.astype(jnp.float32).reshape(*x.shape[:-1], -1, 2)
+        x1, x2 = pairs[..., 0], pairs[..., 1]
+    else:
+        x1, x2 = jnp.split(x.astype(jnp.float32), 2, axis=-1)
     out = jnp.concatenate([x1 * cos - x2 * sin, x1 * sin + x2 * cos], axis=-1)
     return out.astype(x.dtype)
 

@@ -30,7 +30,8 @@ def reduced_config(cfg: ModelConfig, *, layers: int = None,
     """Tiny same-family config for CPU smoke tests.
 
     Keeps the *structure* (pattern, GQA ratio, qk_norm, softcaps, MoE
-    top-k, SSD/RG-LRU mixers, frontend) while shrinking width/depth/vocab.
+    top-k and a held share of the experts, latent attention, SSD/RG-LRU
+    mixers, frontend) while shrinking width/depth/vocab.
     """
     n_pat = len(cfg.pattern)
     depth = layers if layers is not None else max(
@@ -51,10 +52,15 @@ def reduced_config(cfg: ModelConfig, *, layers: int = None,
         remat="none",
     )
     if cfg.num_experts:
+        # the router's 8 outputs, half of them held here
         updates.update(num_experts=min(cfg.num_experts, 8),
+                       experts_held=min(cfg.num_experts, 8) // 2,
                        top_k=min(cfg.top_k, 2), expert_d_ff=64,
-                       capacity_factor=8.0,
                        first_dense_ff=256 if cfg.first_dense_layers else 0)
+    if cfg.mla:
+        # a query-key width (16 + 8) unequal to the value width (16)
+        updates.update(kv_lora_rank=32, qk_nope_head_dim=16,
+                       qk_rope_head_dim=8, v_head_dim=16)
     if cfg.family == "ssm":
         updates.update(ssm_state=16, ssm_head_dim=16, ssd_chunk=16)
     if cfg.lru_width:

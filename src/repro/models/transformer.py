@@ -39,21 +39,23 @@ def stack_specs(specs, n: int):
 # -- block definitions -------------------------------------------------------
 
 
+def attn_specs(cfg: ModelConfig) -> Dict[str, Any]:
+    return attn.mla_specs(cfg) if cfg.mla else attn.attention_specs(cfg)
+
+
 def block_specs(cfg: ModelConfig, kind: str, dense_ff: Optional[int] = None
                 ) -> Dict[str, Any]:
     e = cfg.d_model
     norm = lambda: ParamSpec((e,), ("embed",), "zeros")  # noqa: E731
     if kind in ATTN_KINDS:
-        specs = {"ln1": norm(), "attn": attn.attention_specs(cfg),
-                 "ln2": norm()}
+        specs = {"ln1": norm(), "attn": attn_specs(cfg), "ln2": norm()}
         if cfg.family == "moe" and dense_ff is None:
             specs["ffn"] = moe_mod.moe_specs(cfg)
         else:
             specs["ffn"] = mlp_specs(cfg, d_ff=dense_ff)
         return specs
     if kind == "moe":
-        return {"ln1": norm(), "attn": attn.attention_specs(cfg),
-                "ln2": norm(),
+        return {"ln1": norm(), "attn": attn_specs(cfg), "ln2": norm(),
                 "ffn": (mlp_specs(cfg, d_ff=dense_ff) if dense_ff
                         else moe_mod.moe_specs(cfg))}
     if kind == "ssd":
@@ -64,34 +66,44 @@ def block_specs(cfg: ModelConfig, kind: str, dense_ff: Optional[int] = None
     raise ValueError(kind)
 
 
-def block_apply(params, x, cfg: ModelConfig, kind: str, aux):
-    """One block, training/prefill path (full sequence)."""
+def block_apply(params, x, cfg: ModelConfig, kind: str, stats,
+                mixer=lambda f: f):
+    """One block, training/prefill path (full sequence).  ``stats``: the
+    step's readings so far (``moe.zero_stats``), returned with this
+    block's added.  ``mixer`` wraps the attention of an attention block
+    (``CausalLM``'s rematerialisation under ``remat="nested"``)."""
     x = constrain(x, ("batch", "seq", "embed"))
     if kind in ("global", "local", "moe"):
         a_kind = "local" if kind == "local" else "global"
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        with jax.named_scope("attn"):
-            x = x + attn.attention_apply(params["attn"], h, cfg, kind=a_kind)
+        if cfg.mla:
+            with jax.named_scope("mla"):
+                x = x + mixer(lambda p, h: attn.mla_apply(p, h, cfg))(
+                    params["attn"], h)
+        else:
+            with jax.named_scope("attn"):
+                x = x + mixer(lambda p, h: attn.attention_apply(
+                    p, h, cfg, kind=a_kind))(params["attn"], h)
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
         if "router" in params["ffn"]:
             with jax.named_scope("moe"):
-                y, a = moe_mod.moe_apply(params["ffn"], h, cfg)
-            aux = aux + a
+                y, layer = moe_mod.moe_apply(params["ffn"], h, cfg)
+            stats = moe_mod.add_stats(stats, layer)
         else:
             with jax.named_scope("mlp"):
                 y = mlp_apply(params["ffn"], h, cfg)
-        return x + y, aux
+        return x + y, stats
     if kind == "ssd":
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
         with jax.named_scope("ssd"):
-            return x + ssm_mod.ssd_apply(params["mixer"], h, cfg), aux
+            return x + ssm_mod.ssd_apply(params["mixer"], h, cfg), stats
     if kind == "rglru":
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
         with jax.named_scope("rglru"):
             x = x + rglru_mod.rglru_mixer_apply(params["mixer"], h, cfg)
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
         with jax.named_scope("mlp"):
-            return x + mlp_apply(params["ffn"], h, cfg), aux
+            return x + mlp_apply(params["ffn"], h, cfg), stats
     raise ValueError(kind)
 
 
@@ -99,6 +111,8 @@ def block_apply(params, x, cfg: ModelConfig, kind: str, aux):
 
 
 def block_cache_specs(cfg, kind: str, batch: int, max_len: int):
+    if cfg.mla:
+        return attn.mla_cache_specs(cfg, batch, max_len)
     if kind in ("global", "moe"):
         return attn.cache_specs(cfg, batch, max_len)
     if kind == "local":
@@ -115,14 +129,16 @@ def block_decode(params, x, cfg, kind: str, cache, pos):
     x = constrain(x, ("batch", "seq", "embed"))
     if kind in ("global", "local", "moe"):
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        window = cfg.sliding_window if kind == "local" else None
-        y, cache = attn.decode_attention(params["attn"], h, cfg, cache, pos,
-                                         window=window)
+        if cfg.mla:
+            y, cache = attn.mla_decode(params["attn"], h, cfg, cache, pos)
+        else:
+            window = cfg.sliding_window if kind == "local" else None
+            y, cache = attn.decode_attention(params["attn"], h, cfg, cache,
+                                             pos, window=window)
         x = x + y
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
         if "router" in params["ffn"]:
-            y, _ = moe_mod.moe_apply(params["ffn"], h, cfg,
-                                     capacity_factor=float(cfg.top_k))
+            y, _ = moe_mod.moe_apply(params["ffn"], h, cfg)
         else:
             y = mlp_apply(params["ffn"], h, cfg)
         return x + y, cache
@@ -145,8 +161,12 @@ def block_prefill(params, x, cfg, kind: str, max_len: int):
     if kind in ("global", "local", "moe"):
         a_kind = "local" if kind == "local" else "global"
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
-        y, cache = attn.attention_prefill(params["attn"], h, cfg,
-                                          kind=a_kind, cache_len=max_len)
+        if cfg.mla:
+            y, cache = attn.mla_prefill(params["attn"], h, cfg,
+                                        cache_len=max_len)
+        else:
+            y, cache = attn.attention_prefill(params["attn"], h, cfg,
+                                              kind=a_kind, cache_len=max_len)
         x = x + y
         h = rms_norm(x, params["ln2"], cfg.norm_eps)
         if "router" in params["ffn"]:
@@ -209,34 +229,61 @@ class CausalLM:
 
     # ---- forward (training / prefill trunk) ----
 
+    def _remat(self, fn):
+        """``fn`` (a layer, or the scan's repetition of the pattern) under
+        the configuration's rematerialisation policy."""
+        cfg = self.cfg
+        if cfg.remat == "none":
+            return fn
+        policy = (jax.checkpoint_policies.checkpoint_dots
+                  if cfg.remat == "dots" else
+                  jax.checkpoint_policies.nothing_saveable)
+        return jax.checkpoint(fn, policy=policy, prevent_cse=False)
+
+    def _mixer(self, fn):
+        """The attention within a rematerialised layer: under
+        ``remat="nested"`` rematerialised once more, in its own backward
+        pass, so that its intermediates (MLA's attention core at 8k) are
+        not live through the feed-forward's backward pass."""
+        if self.cfg.remat != "nested":
+            return fn
+        return jax.checkpoint(fn,
+                              policy=jax.checkpoint_policies.nothing_saveable)
+
+    def scan_unit(self):
+        """The layer scan's body, one repetition of the pattern under the
+        configuration's rematerialisation:
+        ``((x, stats), unit_params) -> ((x, stats), None)``."""
+        cfg = self.cfg
+
+        def unit(carry, unit_params):
+            x, stats = carry
+            for j, kind in enumerate(cfg.pattern):
+                x, stats = block_apply(unit_params[f"p{j}"], x, cfg, kind,
+                                       stats, mixer=self._mixer)
+            return (x, stats), None
+        return self._remat(unit)
+
     def _trunk(self, params, x):
         cfg = self.cfg
         reps, tail = self._pattern_layout()
-        aux = jnp.zeros((), jnp.float32)
+        stats = moe_mod.zero_stats(cfg)
+
+        def one(kind):
+            return self._remat(lambda carry, p: block_apply(
+                p, carry[0], cfg, kind, carry[1], mixer=self._mixer))
         for i in range(cfg.first_dense_layers):
-            x, aux = block_apply(params[f"head{i}"], x, cfg,
-                                 cfg.pattern[0], aux)
+            x, stats = one(cfg.pattern[0])((x, stats), params[f"head{i}"])
         if reps > 0:
-            def unit(carry, unit_params):
-                x, aux = carry
-                for j, kind in enumerate(cfg.pattern):
-                    x, aux = block_apply(unit_params[f"p{j}"], x, cfg,
-                                         kind, aux)
-                return (x, aux), None
-            if cfg.remat != "none":
-                policy = (jax.checkpoint_policies.nothing_saveable
-                          if cfg.remat == "nothing_saveable" else
-                          jax.checkpoint_policies.checkpoint_dots)
-                unit = jax.checkpoint(unit, policy=policy,
-                                      prevent_cse=False)
-            (x, aux), _ = jax.lax.scan(unit, (x, aux), params["blocks"])
+            (x, stats), _ = jax.lax.scan(self.scan_unit(), (x, stats),
+                                         params["blocks"])
         for t in range(tail):
-            x, aux = block_apply(params[f"tail{t}"], x, cfg,
-                                 cfg.pattern[t], aux)
-        return rms_norm(x, params["final_norm"], cfg.norm_eps), aux
+            x, stats = one(cfg.pattern[t])((x, stats), params[f"tail{t}"])
+        return rms_norm(x, params["final_norm"], cfg.norm_eps), stats
 
     def _hidden(self, params, tokens, extra_embeds=None):
-        """Embedding and trunk: the final hidden states and the aux loss."""
+        """Embedding and trunk: the final hidden states and the step's
+        readings (``moe.zero_stats``)."""
         cfg = self.cfg
         x = embed_apply(params["embed"], tokens, cfg)
         if extra_embeds is not None:
@@ -251,12 +298,15 @@ class CausalLM:
     def forward(self, params, tokens, extra_embeds=None):
         """tokens: (B, S_text). extra_embeds: (B, S_front, E) modality stub
         prepended to the sequence (VLM patches / audio frames)."""
-        x, aux = self._hidden(params, tokens, extra_embeds)
-        return self._logits(params, x), aux
+        x, stats = self._hidden(params, tokens, extra_embeds)
+        return self._logits(params, x), stats["aux"]
 
     def loss(self, params, batch):
         """batch: tokens (B,S), labels (B,S) [-1 = masked], optional
-        frontend embeds."""
+        frontend embeds.  Returns the loss with the balance loss added,
+        and its parts: ``ce``, ``aux`` and ``counters``, the MoE layers'
+        counters of ``moe.zero_stats`` (none for a model without
+        experts)."""
         cfg = self.cfg
         labels = batch["labels"]
         mask = (labels >= 0)
@@ -264,7 +314,8 @@ class CausalLM:
         denom = jnp.maximum(mask.sum(), 1)
 
         fr = batch.get("frontend")
-        x, aux = self._hidden(params, batch["tokens"], fr)
+        x, stats = self._hidden(params, batch["tokens"], fr)
+        aux = stats.pop("aux")
         with jax.named_scope("lm_head_loss"):
             if cfg.ce_chunk:
                 # chunked CE: unembed + log-softmax per sequence chunk —
@@ -290,7 +341,7 @@ class CausalLM:
                 ll = jnp.take_along_axis(logp, labels[..., None],
                                          axis=-1)[..., 0]
                 loss = -(ll * mask).sum() / denom
-        return loss + aux, {"ce": loss, "aux": aux}
+        return loss + aux, {"ce": loss, "aux": aux, "counters": stats}
 
     # ---- serving ----
 

@@ -163,16 +163,20 @@ class ElasticTrainer:
                     micro, (zeros, jnp.zeros((), jnp.float32)), mbs)
                 grads = jax.tree.map(lambda g: g / accum, grads)
                 loss = loss / accum
+                counters = {}
             else:
-                (loss, _parts), grads = jax.value_and_grad(
+                (loss, parts), grads = jax.value_and_grad(
                     loss_fn, has_aux=True)(state["params"], batch)
+                counters = parts.get("counters", {})
             with jax.named_scope("adamw"):
                 params, opt, metrics = apply_updates(
                     opt_cfg, state["params"], grads, state["opt"])
             new_state = {"params": params, "opt": opt,
                          "rng": jax.random.fold_in(state["rng"], 0),
                          "step": state["step"] + 1}
-            metrics = dict(metrics, loss=loss)
+            # the model's counters (MoE: moe_held_rows, moe_load_max) come
+            # with a single-batch step
+            metrics = dict(metrics, loss=loss, **counters)
             return new_state, metrics
 
         donate = (0,) if self.cfg.donate else ()

@@ -94,6 +94,7 @@ def test_exact_config_matches_assignment(arch):
         "seamless-m4t-medium": (12, 1024, 16, 16, 4096, 256206),
         "mamba2-130m": (24, 768, 0, 0, 0, 50280),
         "paligemma-3b": (18, 2048, 8, 1, 16384, 257216),
+        "deepseek-v2-lite": (27, 2048, 16, 16, 10944, 102400),
     }[cfg.name]
     got = (cfg.num_layers, cfg.d_model, cfg.num_heads, cfg.num_kv_heads,
            cfg.d_ff, cfg.vocab_size)
@@ -105,3 +106,10 @@ def test_exact_config_matches_assignment(arch):
         assert (cfg.num_experts, cfg.top_k) == (16, 2)
     if cfg.name == "mamba2-130m":
         assert cfg.ssm_state == 128
+    if cfg.name == "deepseek-v2-lite":
+        assert (cfg.num_experts, cfg.held_experts, cfg.top_k,
+                cfg.num_shared_experts, cfg.expert_d_ff) == \
+            (64, 64, 6, 2, 1408)
+        assert (cfg.kv_lora_rank, cfg.qk_nope_head_dim, cfg.qk_rope_head_dim,
+                cfg.v_head_dim) == (512, 128, 64, 128)
+        assert cfg.rope_scaling["factor"] == 40 and not cfg.norm_topk_prob
