@@ -140,15 +140,17 @@ def rules_for_shape(shape_name: str, global_batch: int, mesh: Mesh,
 # GSPMD propagation alone mis-shards activations when weights carry exotic
 # shardings (e.g. FSDP embed-dim sharding leaking through the embedding
 # gather).  Model code calls ``constrain(x, logical)`` at block boundaries;
-# it is a no-op unless a (mesh, rules) context is active — set by the cell
-# builder / trainer around tracing.
+# it is a no-op unless a (mesh, rules) context is active — set around
+# tracing by ``launch/cells.py`` and ``launch/units.py``.  The trainer traces its step under the mesh alone
+# (rules None): kernels read it to pick their platform and to map
+# themselves over the devices, and ``constrain`` stays a no-op.
 
 _ACT_CTX: contextvars.ContextVar = contextvars.ContextVar(
     "activation_rules", default=None)
 
 
 @contextlib.contextmanager
-def activation_rules(mesh: Mesh, rules: "ShardingRules"):
+def activation_rules(mesh: Mesh, rules: "ShardingRules | None"):
     tok = _ACT_CTX.set((mesh, rules))
     try:
         yield
@@ -156,10 +158,16 @@ def activation_rules(mesh: Mesh, rules: "ShardingRules"):
         _ACT_CTX.reset(tok)
 
 
-def constrain(x, logical):
-    """Pin an activation to its logical sharding (no-op without context)."""
+def context_mesh() -> "Mesh | None":
+    """The mesh the code is being traced for, or None outside a context."""
     ctx = _ACT_CTX.get()
-    if ctx is None:
+    return None if ctx is None else ctx[0]
+
+
+def constrain(x, logical):
+    """Pin an activation to its logical sharding (no-op without rules)."""
+    ctx = _ACT_CTX.get()
+    if ctx is None or ctx[1] is None:
         return x
     mesh, rules = ctx
     return jax.lax.with_sharding_constraint(

@@ -24,7 +24,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.core import (DMR, TP_DP_RULES, Action, ReshardPlan, ShardingRules,
                         make_mesh, mesh_num_slices, moved_bytes, plan_reshard,
                         reshard)
-from repro.core.sharding import logical_to_sharding
+from repro.core.sharding import activation_rules, logical_to_sharding
 from repro.data import DataConfig, SyntheticLMData
 from repro.checkpoint.store import CheckpointStore
 from repro.optim import AdamWConfig, apply_updates, init_state, state_logical
@@ -147,7 +147,7 @@ class ElasticTrainer:
             loss, parts = model.loss(params, batch)
             return loss, parts
 
-        def train_step(state, batch):
+        def step(state, batch):
             if accum > 1:
                 def micro(c, mb):
                     (loss, parts), grads = jax.value_and_grad(
@@ -178,6 +178,12 @@ class ElasticTrainer:
             # with a single-batch step
             metrics = dict(metrics, loss=loss, **counters)
             return new_state, metrics
+
+        def train_step(state, batch):
+            # the model sees the mesh it is traced for (its kernels pick
+            # their platform by it); activations are left to GSPMD
+            with activation_rules(mesh, None):
+                return step(state, batch)
 
         donate = (0,) if self.cfg.donate else ()
         return jax.jit(train_step, in_shardings=(shardings, batch_sh),

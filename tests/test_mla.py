@@ -1,7 +1,8 @@
 """Multi-head latent attention and YaRN rope (DeepSeek-V2) against numbers
 worked out by hand from the published formulas (HF ``modeling_deepseek``)
 and against plain per-head forms; chunked attention at a value width that
-differs from the query's."""
+differs from the query's; the fused kernel that runs MLA's core on a TPU,
+in interpret mode, against the chunked core."""
 import dataclasses
 import math
 
@@ -11,7 +12,8 @@ import numpy as np
 import pytest
 
 from repro.models import get_model, reduced_config
-from repro.models.attention import (chunked_attention, mla_apply,
+from repro.models.attention import (chunked_attention,
+                                    fused_causal_attention, mla_apply,
                                     mla_scale, mla_specs)
 from repro.models.layers import (apply_rope, init_from_specs, rms_norm,
                                  rope_frequencies, rope_mscale)
@@ -174,3 +176,147 @@ def test_nested_remat_keeps_loss_and_gradients(arch):
         for a, b in zip(jax.tree.leaves(g2), jax.tree.leaves(grads)):
             np.testing.assert_allclose(np.asarray(a), np.asarray(b),
                                        rtol=1e-4, atol=1e-6)
+
+
+# -- the fused core (JAX's splash-attention kernel, in interpret mode) -------
+
+
+def _core_inputs(b, s, h, d, dv, seed=7):
+    ks = jax.random.split(jax.random.PRNGKey(seed), 4)
+    return (jax.random.normal(ks[0], (b, s, h, d)),
+            jax.random.normal(ks[1], (b, s, h, d)),
+            jax.random.normal(ks[2], (b, s, h, dv)),
+            jax.random.normal(ks[3], (b, s, h, dv)))
+
+
+def _gap(got, want):
+    """Largest gap over the largest magnitude of the reference."""
+    return float(jnp.max(jnp.abs(got - want)) / jnp.max(jnp.abs(want)))
+
+
+@pytest.mark.parametrize("block", [128, 256])
+@pytest.mark.parametrize("d,dv,s,h", [
+    (24, 16, 256, 4),            # the reduced configuration's widths
+    (192, 128, 512, 2),          # DeepSeek-V2-Lite's
+])
+def test_fused_core_matches_chunked(block, d, dv, s, h):
+    """Causal, at MLA's scale: the forward and the gradients to q, k and v
+    of the fused kernel match the chunked core's."""
+    cfg = dataclasses.replace(_mla_cfg(), attn_chunk=64)
+    scale = mla_scale(get_model("deepseek-v2-lite")[1])
+    q, k, v, ct = _core_inputs(2, s, h, d, dv)
+
+    def fused(q, k, v):
+        return fused_causal_attention(q, k, v, scale, block=block,
+                                      interpret=True)
+
+    def chunked(q, k, v):
+        return chunked_attention(q, k, v, cfg, causal=True, window=None,
+                                 scale=scale)
+
+    with jax.default_matmul_precision("highest"):
+        got, want = jax.jit(fused)(q, k, v), jax.jit(chunked)(q, k, v)
+        grads = [jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * ct),
+                                  (0, 1, 2)))(q, k, v)
+                 for f in (fused, chunked)]
+    assert got.shape == (2, s, h, dv)
+    assert _gap(got, want) < 2e-6
+    for g, w in zip(*grads):
+        assert _gap(g, w) < 2e-5
+
+
+def test_mla_layer_on_the_kernel_path_under_nested_remat(monkeypatch):
+    """The reduced DeepSeek-V2-Lite model under ``remat="nested"`` (its
+    MLA forward three times a step): the kernel path gives the loss and the
+    gradients the chunked path does.  Every layer's MLP is dense here: the
+    routed experts' top-k turns a rounding-sized change of a layer's output
+    into a change of the experts a token meets."""
+    from repro.models import attention, build_model
+    cfg = _mla_cfg()
+    cfg = dataclasses.replace(cfg, remat="nested", attn_chunk=64,
+                              first_dense_layers=cfg.num_layers)
+    model = build_model(cfg)
+    params = model.init(jax.random.PRNGKey(8))
+    toks = jax.random.randint(jax.random.PRNGKey(9), (2, 256), 0,
+                              cfg.vocab_size)
+    batch = {"tokens": toks, "labels": toks}
+
+    def loss_and_grads():
+        with jax.default_matmul_precision("highest"):
+            return jax.jit(jax.value_and_grad(model.loss, has_aux=True))(
+                params, batch)
+    (loss, _), grads = loss_and_grads()
+    real = attention.fused_causal_attention
+    calls = []
+
+    def fused(*args, **kw):
+        calls.append(kw.get("mesh"))
+        return real(*args, **dict(kw, block=128, interpret=True))
+    monkeypatch.setattr(attention, "_traced_platform", lambda mesh: "tpu")
+    monkeypatch.setattr(attention, "fused_causal_attention", fused)
+    (k_loss, _), k_grads = loss_and_grads()
+    assert calls and calls[0] is None
+    np.testing.assert_allclose(float(k_loss), float(loss), rtol=1e-6)
+    for g, w in zip(jax.tree.leaves(k_grads), jax.tree.leaves(grads)):
+        assert g.shape == w.shape
+        np.testing.assert_allclose(np.asarray(g), np.asarray(w),
+                                   rtol=1e-4, atol=1e-6)
+
+
+def test_fused_core_per_batch_shard_on_four_devices():
+    """On a mesh of 4 devices the kernel runs per shard of the batch
+    (``shard_map``): the forward and the gradients match the chunked core
+    on one device."""
+    import json
+    import os
+    import subprocess
+    import sys
+    import textwrap
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    code = textwrap.dedent("""
+        import dataclasses, json
+        import jax, jax.numpy as jnp
+        from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro.core import make_mesh
+        from repro.models import get_model, reduced_config
+        from repro.models.attention import (chunked_attention,
+                                            fused_causal_attention)
+        cfg = dataclasses.replace(
+            reduced_config(get_model("deepseek-v2-lite")[1]), attn_chunk=64)
+        mesh = make_mesh(4, 1)
+        ks = jax.random.split(jax.random.PRNGKey(0), 4)
+        shapes = [(4, 256, 2, 24)] * 2 + [(4, 256, 2, 16)] * 2
+        q, k, v, ct = [jax.device_put(jax.random.normal(kk, sh),
+                                      NamedSharding(mesh, P("data")))
+                       for kk, sh in zip(ks, shapes)]
+        fused = lambda q, k, v: fused_causal_attention(
+            q, k, v, 0.3, mesh=mesh, block=128, interpret=True)
+        chunked = lambda q, k, v: chunked_attention(
+            q, k, v, cfg, causal=True, window=None, scale=0.3)
+        gap = lambda a, b: float(jnp.max(jnp.abs(a - b))
+                                 / jnp.max(jnp.abs(b)))
+        out = {}
+        with jax.default_matmul_precision("highest"):
+            lowered = jax.jit(fused).lower(q, k, v).as_text()
+            got = jax.jit(fused)(q, k, v)
+            want = jax.jit(chunked)(q, k, v)
+            grads = [jax.jit(jax.grad(lambda *a, f=f: jnp.sum(f(*a) * ct),
+                                      (0, 1, 2)))(q, k, v)
+                     for f in (fused, chunked)]
+        out["shard_map"] = "sdy.manual_computation" in lowered \\
+            or "shard_map" in lowered
+        out["out_sharding"] = str(got.sharding.spec)
+        out["fwd"] = gap(got, want)
+        out["grads"] = [gap(g, w) for g, w in zip(*grads)]
+        print(json.dumps(out))
+        """)
+    env = dict(os.environ, XLA_FLAGS="--xla_force_host_platform_device_count=4",
+               PYTHONPATH=os.path.join(repo, "src"), JAX_PLATFORMS="cpu")
+    proc = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                          text=True, env=env, timeout=600)
+    assert proc.returncode == 0, proc.stderr[-4000:]
+    out = json.loads(proc.stdout.strip().splitlines()[-1])
+    assert out["shard_map"], out
+    assert "data" in out["out_sharding"]
+    assert out["fwd"] < 2e-6
+    assert max(out["grads"]) < 2e-5

@@ -100,3 +100,110 @@ def test_smollm_decode_step_fits_one_chip(one_chip):
     mem = compiled.memory_analysis()
     used = mem.argument_size_in_bytes + mem.temp_size_in_bytes
     assert 0 < used < V5E_HBM_BYTES
+
+
+# -- multi-head latent attention's core: the fused kernel where the step is
+# -- compiled for a TPU, the chunked core elsewhere
+
+
+def _train_step_text(one_chip, arch, seq_len):
+    """The reduced ``arch``'s train step, built through the launcher as the
+    benchmark builds it and compiled for the described chip on the
+    trainer's own one-device mesh."""
+    from repro.core import make_mesh
+    from repro.launch.train import build
+    trainer, _ = build(arch, reduced=True, seq_len=seq_len, global_batch=2)
+    dev = next(iter(one_chip.device_set))
+    mesh = make_mesh(1, 1, devices=[dev])
+    trainer.devices, trainer.mesh = [dev], mesh
+    shardings = trainer._state_shardings(mesh)
+    state = jax.tree.map(
+        lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+        jax.eval_shape(lambda: trainer._fresh_state(0)), shardings)
+    bs = trainer._batch_shardings(mesh)
+    batch = {k: jax.ShapeDtypeStruct((2, seq_len), jnp.int32,
+                                     sharding=bs[k])
+             for k in ("tokens", "labels")}
+    with mesh:
+        return trainer.step_fn(mesh).lower(state, batch).compile().as_text()
+
+
+@pytest.mark.parametrize("arch,fused", [("deepseek-v2-lite", True),
+                                        ("smollm-135m", False)])
+def test_step_runs_the_fused_core_where_attention_is_latent(one_chip, arch,
+                                                            fused):
+    """DeepSeek-V2-Lite's step holds the splash kernels (forward, dq, dkv);
+    SmolLM's grouped-query attention keeps the chunked core, no kernel."""
+    text = _train_step_text(one_chip, arch, 256)
+    for phase in ("fwd", "dq", "dkv"):
+        assert (f"splash_mha_{phase}" in text) == fused
+    assert ("tpu_custom_call" in text) == fused
+
+
+@pytest.mark.parametrize("seq,fused", [(384, True), (200, False)])
+def test_mla_forward_on_the_chip_by_sequence_length(one_chip, seq, fused):
+    """Traced for the chip, MLA takes the kernel for sequences of whole
+    128-wide lanes (blocks of 128 for 384) and the chunked core for the
+    rest."""
+    import dataclasses
+
+    from jax.sharding import Mesh
+
+    from repro.core.sharding import activation_rules
+    from repro.models import reduced_config
+    from repro.models.attention import mla_apply, mla_specs
+    from repro.models.layers import init_from_specs
+    cfg = dataclasses.replace(reduced_config(get_model("deepseek-v2-lite")[1]),
+                              attn_chunk=40)
+    params = jax.eval_shape(lambda: init_from_specs(jax.random.PRNGKey(0),
+                                                    mla_specs(cfg)))
+    dev = next(iter(one_chip.device_set))
+    params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=one_chip), params)
+    x = jax.ShapeDtypeStruct((2, seq, cfg.d_model), BF16, sharding=one_chip)
+    with activation_rules(Mesh([dev], ("data",)), None):
+        compiled = jax.jit(lambda p, x: mla_apply(p, x, cfg)).lower(
+            params, x).compile()
+    assert ("splash_mha_fwd" in compiled.as_text()) == fused
+
+
+def test_fused_core_compiles_at_the_cell_widths(one_chip):
+    """The core of ``deepseek-v2-lite.train``: 2 rows of 8,192 tokens, 16
+    heads, query-key width 192, value width 128, bf16, forward and
+    backward, in well under a gigabyte of temporaries."""
+    from repro.models.attention import fused_causal_attention
+
+    def loss(q, k, v):
+        return jnp.sum(fused_causal_attention(q, k, v, 0.1147)
+                       .astype(F32))
+    compiled = compile_for(
+        one_chip, jax.grad(loss, (0, 1, 2)),
+        ((2, 8192, 16, 192), BF16), ((2, 8192, 16, 192), BF16),
+        ((2, 8192, 16, 128), BF16))
+    text = compiled.as_text()
+    for phase in ("fwd", "dq", "dkv"):
+        assert f"splash_mha_{phase}" in text
+    assert compiled.memory_analysis().temp_size_in_bytes < 1e9
+
+
+def test_mla_forward_lowers_without_the_kernel_on_cpu():
+    """On the CPU, outside a mesh and under a CPU mesh alike, MLA's core
+    is the chunked one: nothing of the kernel is lowered."""
+    import dataclasses
+
+    from repro.core import make_mesh
+    from repro.core.sharding import activation_rules
+    from repro.models import reduced_config
+    from repro.models.attention import mla_apply, mla_specs
+    from repro.models.layers import init_from_specs
+    cfg = reduced_config(get_model("deepseek-v2-lite")[1])
+    cfg = dataclasses.replace(cfg, attn_chunk=64)
+    params = init_from_specs(jax.random.PRNGKey(0), mla_specs(cfg))
+    x = jnp.zeros((2, 256, cfg.d_model), BF16)
+    loss = jax.grad(lambda p, x: jnp.sum(mla_apply(p, x, cfg)
+                                         .astype(F32)))
+    texts = [jax.jit(loss).lower(params, x).as_text()]
+    with activation_rules(make_mesh(1, 1), None):
+        texts.append(jax.jit(loss).lower(params, x).as_text())
+    for text in texts:
+        assert "tpu_custom_call" not in text and "splash" not in text
