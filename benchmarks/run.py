@@ -12,7 +12,6 @@ import time
 def main() -> None:
     quick = "--quick" in sys.argv
     from benchmarks import (fig3_reconfig_overhead, fig6_trace,
-                            kernel_bench, lm_cluster, roofline_report,
                             table2_actions, table3_sync_vs_async,
                             table4_throughput)
     benches = [
@@ -21,9 +20,6 @@ def main() -> None:
         ("table3_sync_vs_async", table3_sync_vs_async.main),
         ("table4_throughput", table4_throughput.main),
         ("fig6_trace", fig6_trace.main),
-        ("lm_cluster", lm_cluster.main),
-        ("kernel_bench", kernel_bench.main),
-        ("roofline_report", roofline_report.main),
     ]
     for name, fn in benches:
         print(f"\n===== {name} =====")

@@ -9,10 +9,11 @@ Two backends close the loop between the jax runtime and the RMS simulator:
   plans of :mod:`repro.core.redistribute` describe (the reshard tests pin
   that equivalence).  ``migrate_slice`` (the straggler path) is timed the
   same way, and RMS scheduling latency is sampled from real
-  ``ReconfigPolicy.decide`` calls, reusing the ``kernel_bench`` timing
-  pattern (warm-up, ``block_until_ready``, best-of-``repeats``).  On a
-  host with fewer devices than a geometry needs (the 1-device CI CPU
-  default), the harness falls back to a *link proxy*: it times a
+  ``ReconfigPolicy.decide`` calls.  Each reading is one warm-up call, then
+  the best of ``repeats`` timed calls (device work is waited for with
+  ``block_until_ready``, :func:`_best_of`).  On a host with fewer devices
+  than a geometry needs (the 1-device CI CPU default), the harness falls
+  back to a *link proxy*: it times a
   host→device ``device_put`` of the plan's busiest-link bytes, which is
   the quantity the Fig. 3 model divides by ``link_bw`` — honest bandwidth
   measurement, no synthetic numbers.  Multi-device CPU meshes are
@@ -142,7 +143,7 @@ def _measure_plan(config: MeasureConfig
 # ---------------------------------------------------------------------------
 
 def _best_of(fn, repeats: int) -> float:
-    """kernel_bench-style timing: one warm-up call, then best of N."""
+    """One warm-up call, then the best of N timed calls."""
     import jax
     jax.block_until_ready(fn())
     best = float("inf")

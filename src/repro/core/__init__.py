@@ -10,7 +10,7 @@ from repro.core.reshard import (ReshardPlan, checkpoint_reshard,
                                 crosses_host, moved_bytes, ownership_map,
                                 plan_reshard, reshard, state_shardings)
 from repro.core.sharding import (FSDP_RULES, LONG_CONTEXT_RULES, TP_DP_RULES,
-                                 ShardingRules, rules_for_shape)
+                                 ShardingRules)
 
 __all__ = [
     "Action", "Decision", "ResizeHandler", "DMR", "RMSProtocol",
@@ -21,5 +21,4 @@ __all__ = [
     "ReshardPlan", "plan_reshard", "crosses_host",
     "state_shardings", "ownership_map",
     "ShardingRules", "TP_DP_RULES", "FSDP_RULES", "LONG_CONTEXT_RULES",
-    "rules_for_shape",
 ]

@@ -114,7 +114,7 @@ TP_DP_RULES = ShardingRules({
 })
 
 # FSDP-style variant: weights additionally sharded over the data axis
-# (all-gathered at use).  Candidate for the perf hillclimb.
+# (all-gathered at use).
 FSDP_RULES = TP_DP_RULES.replace(embed=("data",))
 
 # Long-context decode (batch too small to shard): shard the KV cache /
@@ -123,55 +123,29 @@ LONG_CONTEXT_RULES = TP_DP_RULES.replace(
     batch=(), kv_seq=("pod", "data"), seq=("pod", "data"))
 
 
-def rules_for_shape(shape_name: str, global_batch: int, mesh: Mesh,
-                    base: ShardingRules = TP_DP_RULES) -> ShardingRules:
-    """Pick a rule table appropriate for an input-shape family."""
-    data_ways = 1
-    for ax in ("pod", "data"):
-        if ax in mesh.shape:
-            data_ways *= mesh.shape[ax]
-    if global_batch < data_ways:
-        return LONG_CONTEXT_RULES
-    return base
-
-
-# -- activation sharding constraints -----------------------------------------
+# -- the mesh a step is traced for -------------------------------------------
 #
-# GSPMD propagation alone mis-shards activations when weights carry exotic
-# shardings (e.g. FSDP embed-dim sharding leaking through the embedding
-# gather).  Model code calls ``constrain(x, logical)`` at block boundaries;
-# it is a no-op unless a (mesh, rules) context is active — set around
-# tracing by ``launch/cells.py`` and ``launch/units.py``.  The trainer traces its step under the mesh alone
-# (rules None): kernels read it to pick their platform and to map
-# themselves over the devices, and ``constrain`` stays a no-op.
+# The trainer traces its step under the mesh it compiles for: kernels read it
+# (``context_mesh``) to pick their platform and to map themselves over the
+# devices.  Activations are left to GSPMD's propagation.
 
-_ACT_CTX: contextvars.ContextVar = contextvars.ContextVar(
-    "activation_rules", default=None)
+_MESH_CTX: contextvars.ContextVar = contextvars.ContextVar(
+    "tracing_mesh", default=None)
 
 
 @contextlib.contextmanager
-def activation_rules(mesh: Mesh, rules: "ShardingRules | None"):
-    tok = _ACT_CTX.set((mesh, rules))
+def tracing_mesh(mesh: Mesh):
+    """Trace the enclosed code for ``mesh`` (see :func:`context_mesh`)."""
+    tok = _MESH_CTX.set(mesh)
     try:
         yield
     finally:
-        _ACT_CTX.reset(tok)
+        _MESH_CTX.reset(tok)
 
 
 def context_mesh() -> "Mesh | None":
     """The mesh the code is being traced for, or None outside a context."""
-    ctx = _ACT_CTX.get()
-    return None if ctx is None else ctx[0]
-
-
-def constrain(x, logical):
-    """Pin an activation to its logical sharding (no-op without rules)."""
-    ctx = _ACT_CTX.get()
-    if ctx is None or ctx[1] is None:
-        return x
-    mesh, rules = ctx
-    return jax.lax.with_sharding_constraint(
-        x, NamedSharding(mesh, rules.spec_for(logical, x.shape, mesh)))
+    return _MESH_CTX.get()
 
 
 def logical_to_sharding(tree_logical, tree_shapes, mesh: Mesh,

@@ -1,5 +1,4 @@
 """Data pipeline."""
-from repro.data.pipeline import (DataConfig, SyntheticLMData, batch_specs,
-                                 make_batch)
+from repro.data.pipeline import DataConfig, SyntheticLMData, make_batch
 
-__all__ = ["DataConfig", "SyntheticLMData", "batch_specs", "make_batch"]
+__all__ = ["DataConfig", "SyntheticLMData", "make_batch"]

@@ -60,23 +60,5 @@ class SyntheticLMData:
         return batch
 
 
-def batch_specs(cfg: DataConfig) -> Dict[str, jax.ShapeDtypeStruct]:
-    """ShapeDtypeStruct stand-ins for the dry-run (no allocation)."""
-    text_len = cfg.seq_len - cfg.frontend_tokens
-    if cfg.enc_dec:
-        text_len = cfg.seq_len // 2
-    out = {
-        "tokens": jax.ShapeDtypeStruct((cfg.global_batch, text_len),
-                                       jnp.int32),
-        "labels": jax.ShapeDtypeStruct((cfg.global_batch, text_len),
-                                       jnp.int32),
-    }
-    if cfg.frontend:
-        out["frontend"] = jax.ShapeDtypeStruct(
-            (cfg.global_batch, cfg.frontend_tokens or cfg.seq_len // 2,
-             cfg.d_model), jnp.float32)
-    return out
-
-
 def make_batch(cfg: DataConfig, step: int) -> Dict[str, jax.Array]:
     return SyntheticLMData(cfg).batch(step)

@@ -9,8 +9,7 @@ Implementation notes (TPU adaptation):
   prefix is a static slice) and each chunk scans its key prefix with a
   running (max, sum, acc) — flash attention expressed in jnp.  This keeps
   the compiled HLO at the exact causal FLOP count (no wasted upper-triangle
-  work) and O(chunk²) live memory, so the dry-run roofline reflects what a
-  production TPU run would do.  GQA attention runs it on every platform.
+  work) and O(chunk²) live memory.  GQA attention runs it on every platform.
 - MLA's causal core runs JAX's fused splash-attention Pallas kernel when
   the step is traced for a TPU (:func:`fused_causal_attention`), and
   :func:`chunked_attention` elsewhere: the platform is that of the mesh the

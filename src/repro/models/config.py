@@ -83,12 +83,7 @@ class ModelConfig:
     # "none" | "nothing_saveable" | "dots" | "nested" (nothing_saveable,
     # and each layer's attention rematerialised again in its own backward)
     remat: str = "nothing_saveable"
-    # attention implementation: "auto" picks pallas on TPU, chunked on CPU
-    attn_impl: str = "auto"
     attn_chunk: int = 512
-    # chunked cross-entropy: compute logits+CE over sequence chunks of this
-    # size (0 = whole sequence at once); bounds the (B,S,V) logits temp
-    ce_chunk: int = 0
 
     def __post_init__(self):
         if self.head_dim == 0 and self.num_heads > 0:
@@ -178,14 +173,3 @@ class ModelConfig:
             total += self.num_layers * (e * (h * hd + 2 * kv * hd)
                                         + h * hd * e + e)
         return total
-
-    def active_param_count(self) -> int:
-        """Parameters touched per token (MoE: the held experts a token
-        chooses on average, and the shared ones)."""
-        if self.family != "moe":
-            return self.param_count()
-        full = self.param_count()
-        held = self.held_experts
-        inactive = (held - self.top_k * held / self.num_experts) * 3 \
-            * self.d_model * self.expert_d_ff * self.num_layers
-        return int(full - inactive)

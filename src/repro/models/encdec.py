@@ -13,7 +13,6 @@ from typing import Any, Dict
 import jax
 import jax.numpy as jnp
 
-from repro.core.sharding import constrain
 from repro.models import attention as attn
 from repro.models.config import ModelConfig
 from repro.models.layers import (ParamSpec, embed_apply, embed_specs,
@@ -38,7 +37,6 @@ def dec_block_specs(cfg) -> Dict[str, Any]:
 
 
 def enc_block_apply(params, x, cfg):
-    x = constrain(x, ("batch", "seq", "embed"))
     h = rms_norm(x, params["ln1"], cfg.norm_eps)
     x = x + attn.attention_apply(params["attn"], h, cfg, kind="global",
                                  causal=False)
@@ -47,7 +45,6 @@ def enc_block_apply(params, x, cfg):
 
 
 def dec_block_apply(params, x, enc_out, cfg):
-    x = constrain(x, ("batch", "seq", "embed"))
     h = rms_norm(x, params["ln1"], cfg.norm_eps)
     x = x + attn.attention_apply(params["self_attn"], h, cfg, kind="global")
     h = rms_norm(x, params["ln_x"], cfg.norm_eps)

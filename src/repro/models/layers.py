@@ -61,12 +61,6 @@ def shape_tree(specs):
     return jax.tree.map(lambda s: s.shape, specs, is_leaf=is_spec)
 
 
-def abstract_params(specs, dtype=jnp.float32):
-    return jax.tree.map(
-        lambda s: jax.ShapeDtypeStruct(s.shape, dtype), specs,
-        is_leaf=is_spec)
-
-
 def param_bytes(specs, bytes_per_el: int = 4) -> int:
     return sum(int(np.prod(s.shape)) * bytes_per_el
                for s in jax.tree.leaves(specs, is_leaf=is_spec))

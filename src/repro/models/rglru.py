@@ -8,9 +8,9 @@ Real-Gated Linear Recurrent Unit:
 
 The temporal mix is: linear in, causal conv1d (width 4), RG-LRU, gated by a
 GeLU branch, linear out.  Training/prefill uses ``jax.lax.associative_scan``
-over time (log-depth, parallel); the Pallas kernel
+over time (log-depth, parallel) on every platform.  The Pallas kernel
 (:mod:`repro.kernels.rglru`) implements the same recurrence with chunked
-VMEM tiles for TPU.
+VMEM tiles but has no caller on the model path.
 """
 from __future__ import annotations
 

@@ -1,12 +1,12 @@
 """Mamba-2 SSD (state-space duality) block — arXiv:2405.21060.
 
-The XLA path implements the *chunked SSD algorithm* (the paper's Listing 1):
-intra-chunk quadratic attention-like term + inter-chunk recurrent state
-carry, scanned over chunks.  This is the same blocking the Pallas kernel
-(:mod:`repro.kernels.ssd`) uses on TPU, so the dry-run HLO reflects the
-production compute/memory pattern.  ``n_groups = 1`` (B/C shared across
-heads), D skip connection, gated RMSNorm, causal conv1d — matching the
-mamba2-130m reference.
+The model runs the *chunked SSD algorithm* (the paper's Listing 1) in XLA on
+every platform: intra-chunk quadratic attention-like term + inter-chunk
+recurrent state carry, scanned over chunks.  The Pallas kernel
+(:mod:`repro.kernels.ssd`) uses the same blocking but has no caller on the
+model path.  ``n_groups = 1`` (B/C shared across heads), D skip
+connection, gated RMSNorm, causal conv1d — matching the mamba2-130m
+reference.
 """
 from __future__ import annotations
 
@@ -53,7 +53,8 @@ def causal_conv1d(x, w, b):
 def ssd_chunked(x, dt, a_log, b, c, chunk: int):
     """Chunked SSD. x: (B,S,H,P); dt: (B,S,H); b,c: (B,S,N) (n_groups=1).
 
-    Returns y: (B,S,H,P).  Exact (fp32 state) — validated against the
+    Returns y: (B,S,H,P).  Exact (fp32 state); ``tests/test_kernels.py``
+    checks it against the Pallas kernel, which is checked against the
     step-recurrence oracle in kernels/ssd/ref.py.
     """
     bsz, s, h, p = x.shape

@@ -4,9 +4,9 @@ A model is a *pattern* of block kinds — e.g. ``("global",)`` (llama-style),
 ``("local", "global")`` (gemma2), ``("rglru", "rglru", "local")``
 (recurrentgemma), ``("ssd",)`` (mamba2), ``("moe",)`` — scanned over
 ``num_layers // len(pattern)`` repeats (plus an unscanned tail when the depth
-is not a multiple).  Scanning keeps trace/compile time O(pattern), which is
-what makes 80 dry-run compiles tractable, and the stacked parameter layout
-["layers", ...] is what the elastic resharding engine moves between meshes.
+is not a multiple).  Scanning keeps trace and compile time O(pattern), not
+O(depth), and the stacked parameter layout ["layers", ...] is what the
+elastic resharding engine moves between meshes.
 """
 from __future__ import annotations
 
@@ -19,7 +19,6 @@ from repro.models import attention as attn
 from repro.models import moe as moe_mod
 from repro.models import rglru as rglru_mod
 from repro.models import ssm as ssm_mod
-from repro.core.sharding import constrain
 from repro.models.config import ModelConfig
 from repro.models.layers import (ParamSpec, embed_apply, embed_specs,
                                  init_from_specs, is_spec, logical_tree,
@@ -72,7 +71,6 @@ def block_apply(params, x, cfg: ModelConfig, kind: str, stats,
     step's readings so far (``moe.zero_stats``), returned with this
     block's added.  ``mixer`` wraps the attention of an attention block
     (``CausalLM``'s rematerialisation under ``remat="nested"``)."""
-    x = constrain(x, ("batch", "seq", "embed"))
     if kind in ("global", "local", "moe"):
         a_kind = "local" if kind == "local" else "global"
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
@@ -126,7 +124,6 @@ def block_cache_specs(cfg, kind: str, batch: int, max_len: int):
 
 
 def block_decode(params, x, cfg, kind: str, cache, pos):
-    x = constrain(x, ("batch", "seq", "embed"))
     if kind in ("global", "local", "moe"):
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
         if cfg.mla:
@@ -157,7 +154,6 @@ def block_decode(params, x, cfg, kind: str, cache, pos):
 
 def block_prefill(params, x, cfg, kind: str, max_len: int):
     """Full-sequence forward that also fills the block cache."""
-    x = constrain(x, ("batch", "seq", "embed"))
     if kind in ("global", "local", "moe"):
         a_kind = "local" if kind == "local" else "global"
         h = rms_norm(x, params["ln1"], cfg.norm_eps)
@@ -288,18 +284,13 @@ class CausalLM:
         x = embed_apply(params["embed"], tokens, cfg)
         if extra_embeds is not None:
             x = jnp.concatenate([extra_embeds.astype(x.dtype), x], axis=1)
-        x = constrain(x, ("batch", "seq", "embed"))
         return self._trunk(params, x)
-
-    def _logits(self, params, x):
-        logits = unembed_apply(params["embed"], x, self.cfg)
-        return constrain(logits, ("batch", "seq", "vocab"))
 
     def forward(self, params, tokens, extra_embeds=None):
         """tokens: (B, S_text). extra_embeds: (B, S_front, E) modality stub
         prepended to the sequence (VLM patches / audio frames)."""
         x, stats = self._hidden(params, tokens, extra_embeds)
-        return self._logits(params, x), stats["aux"]
+        return unembed_apply(params["embed"], x, self.cfg), stats["aux"]
 
     def loss(self, params, batch):
         """batch: tokens (B,S), labels (B,S) [-1 = masked], optional
@@ -317,30 +308,13 @@ class CausalLM:
         x, stats = self._hidden(params, batch["tokens"], fr)
         aux = stats.pop("aux")
         with jax.named_scope("lm_head_loss"):
-            if cfg.ce_chunk:
-                # chunked CE: unembed + log-softmax per sequence chunk —
-                # the (B, S, V) logits never materialize.
-                n_front = fr.shape[1] if fr is not None else 0
-                x = x[:, n_front:]
-                s = x.shape[1]
-                c = cfg.ce_chunk
-                total = jnp.zeros((), jnp.float32)
-                for i in range(0, s, c):
-                    lg = unembed_apply(params["embed"], x[:, i:i + c], cfg)
-                    lp = jax.nn.log_softmax(lg.astype(jnp.float32), axis=-1)
-                    ll = jnp.take_along_axis(
-                        lp, labels[:, i:i + c, None], axis=-1)[..., 0]
-                    total = total + (ll * mask[:, i:i + c]).sum()
-                loss = -total / denom
-            else:
-                logits = self._logits(params, x)
-                if fr is not None:   # frontend positions carry no labels
-                    logits = logits[:, fr.shape[1]:]
-                logp = jax.nn.log_softmax(logits.astype(jnp.float32),
-                                          axis=-1)
-                ll = jnp.take_along_axis(logp, labels[..., None],
-                                         axis=-1)[..., 0]
-                loss = -(ll * mask).sum() / denom
+            logits = unembed_apply(params["embed"], x, cfg)
+            if fr is not None:   # frontend positions carry no labels
+                logits = logits[:, fr.shape[1]:]
+            logp = jax.nn.log_softmax(logits.astype(jnp.float32), axis=-1)
+            ll = jnp.take_along_axis(logp, labels[..., None],
+                                     axis=-1)[..., 0]
+            loss = -(ll * mask).sum() / denom
         return loss + aux, {"ce": loss, "aux": aux, "counters": stats}
 
     # ---- serving ----

@@ -26,10 +26,12 @@ class AdamWConfig:
     warmup_steps: int = 100
     total_steps: int = 10000
     min_lr_ratio: float = 0.1
-    zero1: bool = True          # shard moments over the data axes
-    # dtype for the cross-slice gradient reduction (None = fp32); bf16
-    # halves the dominant DP collective at <1e-3 relative grad error
-    grad_reduce_dtype: str = None
+    # Not settable: what the update always does, as the benchmark's mixes
+    # state it.  The moments are ZeRO-1 sharded over the data axes
+    # (``state_logical``); the gradients are reduced in their own dtype.
+    zero1: bool = dataclasses.field(default=True, init=False)
+    grad_reduce_dtype: Optional[str] = dataclasses.field(default=None,
+                                                         init=False)
 
 
 def schedule(cfg: AdamWConfig, step):
@@ -114,14 +116,10 @@ def zero1_logical(param_logical, param_shape, mesh, rules):
     return tuple(out)
 
 
-def state_logical(params_logical, params_shapes, mesh, rules,
-                  zero1: bool = True):
-    """Logical specs for the optimizer state pytree."""
-    if zero1:
-        mom = jax.tree.map(
-            lambda lg, sh: zero1_logical(lg, sh, mesh, rules),
-            params_logical, params_shapes,
-            is_leaf=lambda x: isinstance(x, tuple))
-    else:
-        mom = params_logical
+def state_logical(params_logical, params_shapes, mesh, rules):
+    """Logical specs for the optimizer state pytree (ZeRO-1 moments)."""
+    mom = jax.tree.map(
+        lambda lg, sh: zero1_logical(lg, sh, mesh, rules),
+        params_logical, params_shapes,
+        is_leaf=lambda x: isinstance(x, tuple))
     return {"mu": mom, "nu": mom, "step": ()}

@@ -5,8 +5,7 @@ quantizes its local gradient shard to int8 (per-block scales), psums the
 int8 payload (in int32 to avoid overflow), dequantizes, and keeps the
 quantization residual to add into the next step's gradient (error
 feedback), which preserves convergence.  Implemented with ``shard_map`` so
-the collective is explicit — the wire traffic drops 4x vs fp32 (the
-roofline collective term of DP-bound cells).
+the collective is explicit — the wire traffic drops 4x vs fp32.
 """
 from __future__ import annotations
 

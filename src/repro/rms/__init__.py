@@ -2,7 +2,7 @@
 from repro.rms.capacity import (CHURN_SCENARIOS, CapacityConfig,
                                 CapacityManager, churn_schedule, plan_drain)
 from repro.rms.cluster import Cluster
-from repro.rms.costmodel import PAPER_APPS, AppModel, ReconfigCostModel, lm_app_model
+from repro.rms.costmodel import PAPER_APPS, AppModel, ReconfigCostModel
 from repro.rms.engine import (CheckpointTick, Event, ExpandTimeout, JobFinish,
                               JobSubmit, NodeDrain, NodeFail, NodeJoin,
                               NodePowerOff, NodePowerOn, PhaseChange,
@@ -19,7 +19,7 @@ from repro.rms.simulator import (ActionRecord, ClusterSimulator, SimConfig,
                                  SimReport)
 
 __all__ = ["Cluster", "PAPER_APPS", "AppModel", "ReconfigCostModel",
-           "lm_app_model", "Job", "JobPhase", "JobState", "PolicyConfig",
+           "Job", "JobPhase", "JobState", "PolicyConfig",
            "ReconfigPolicy", "factor_sizes", "MAX_PRIORITY", "Scheduler",
            "SchedulerConfig", "SchedulingPolicy", "POLICY_REGISTRY",
            "SJFPolicy", "FairSharePolicy", "PreemptiveBackfillPolicy",

@@ -140,22 +140,3 @@ class ReconfigCostModel:
             sync = self.shrink_sync_s
         return self.spawn_s + transfer_time_s(
             plan, link_bw=self.link_bw, sync_s_per_participant=sync)
-
-
-def lm_app_model(name: str, *, params: int, step_flops: float,
-                 iterations: int, chip_flops: float = 197e12,
-                 model_ways: int = 16, mfu: float = 0.4,
-                 min_nodes: int = 1, max_nodes: int = 16,
-                 preferred: Optional[int] = None,
-                 bytes_per_param: int = 18) -> AppModel:
-    """An elastic LM-training job as a malleable app (beyond-paper workload).
-
-    One "node" = one data-parallel slice of ``model_ways`` chips.  Per-step
-    time on P slices ≈ step_flops / (P * model_ways * chip_flops * mfu);
-    state moved on reconfiguration = params + grads + optimizer moments.
-    """
-    t1 = step_flops / (model_ways * chip_flops * mfu)
-    return AppModel(f"lm:{name}", iterations=iterations, t1_iter_s=t1,
-                    serial_frac=0.02, data_bytes=params * bytes_per_param,
-                    min_nodes=min_nodes, max_nodes=max_nodes,
-                    preferred=preferred, check_period_s=30.0)

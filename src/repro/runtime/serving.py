@@ -2,8 +2,8 @@
 
 A :class:`Server` owns a params copy and a slot-based KV cache; requests
 join free slots (prefill), decode steps advance all active slots together,
-finished sequences free their slots.  ``serve_step`` — one fused decode for
-the whole batch — is the unit the decode dry-run cells lower.  The server
+finished sequences free their slots.  ``serve_step`` is one fused decode
+for the whole batch.  The server
 is malleable the same way the trainer is: at reconfiguration points the
 cache+params reshard onto the granted mesh (a serving job can donate chips
 to the queue under the paper's policy).

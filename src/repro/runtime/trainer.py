@@ -21,10 +21,9 @@ import jax.numpy as jnp
 from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.core import (DMR, TP_DP_RULES, Action, ReshardPlan, ShardingRules,
-                        make_mesh, mesh_num_slices, moved_bytes, plan_reshard,
-                        reshard)
-from repro.core.sharding import activation_rules, logical_to_sharding
+from repro.core import (DMR, TP_DP_RULES, Action, ReshardPlan, make_mesh,
+                        mesh_num_slices, moved_bytes, plan_reshard, reshard)
+from repro.core.sharding import logical_to_sharding, tracing_mesh
 from repro.data import DataConfig, SyntheticLMData
 from repro.checkpoint.store import CheckpointStore
 from repro.optim import AdamWConfig, apply_updates, init_state, state_logical
@@ -46,7 +45,6 @@ class TrainerConfig:
     ckpt_dir: Optional[str] = None
     ckpt_period: int = 50
     log_period: int = 10
-    rules: ShardingRules = TP_DP_RULES
     donate: bool = True
 
 
@@ -84,7 +82,7 @@ class ElasticTrainer:
                 jax.tree.map(lambda s: s.shape, self.model.specs(),
                              is_leaf=lambda x: hasattr(x, "shape")
                              and hasattr(x, "logical")),
-                mesh, self.cfg.rules, zero1=self.opt_cfg.zero1),
+                mesh, TP_DP_RULES),
             "rng": (None,),
             "step": (),
         }
@@ -101,7 +99,7 @@ class ElasticTrainer:
             "rng": (2,),
             "step": (),
         }
-        return logical_to_sharding(logical, shapes, mesh, self.cfg.rules)
+        return logical_to_sharding(logical, shapes, mesh, TP_DP_RULES)
 
     def _batch_shardings(self, mesh):
         spec = {"tokens": P(("pod", "data")), "labels": P(("pod", "data"))}
@@ -181,8 +179,8 @@ class ElasticTrainer:
 
         def train_step(state, batch):
             # the model sees the mesh it is traced for (its kernels pick
-            # their platform by it); activations are left to GSPMD
-            with activation_rules(mesh, None):
+            # their platform by it)
+            with tracing_mesh(mesh):
                 return step(state, batch)
 
         donate = (0,) if self.cfg.donate else ()

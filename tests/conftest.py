@@ -1,5 +1,6 @@
-"""Test fixtures. Tests must see the real single CPU device — only
-launch/dryrun.py sets the 512-device placeholder flag."""
+"""Test fixtures. Tests must see the real single CPU device: a test that
+needs several CPU devices starts a subprocess with the device-count flag
+(``tests/test_multidevice.py``)."""
 import os
 
 import jax

@@ -1,9 +1,9 @@
 """Multi-device semantics on CPU host devices (subprocess, 8 devices).
 
-Validates for real what the dry-run only compiles: elastic resharding
-across meshes of different sizes (values + Listing-3 ownership), slice
-migration, and an elastic train loop that expands mid-run without changing
-the math.
+Runs on several devices what the single-device tests cannot: elastic
+resharding across meshes of different sizes (values + Listing-3
+ownership), slice migration, and an elastic train loop that expands
+mid-run without changing the math.
 """
 import json
 import os

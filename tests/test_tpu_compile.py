@@ -149,7 +149,7 @@ def test_mla_forward_on_the_chip_by_sequence_length(one_chip, seq, fused):
 
     from jax.sharding import Mesh
 
-    from repro.core.sharding import activation_rules
+    from repro.core.sharding import tracing_mesh
     from repro.models import reduced_config
     from repro.models.attention import mla_apply, mla_specs
     from repro.models.layers import init_from_specs
@@ -161,7 +161,7 @@ def test_mla_forward_on_the_chip_by_sequence_length(one_chip, seq, fused):
     params = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
         a.shape, a.dtype, sharding=one_chip), params)
     x = jax.ShapeDtypeStruct((2, seq, cfg.d_model), BF16, sharding=one_chip)
-    with activation_rules(Mesh([dev], ("data",)), None):
+    with tracing_mesh(Mesh([dev], ("data",))):
         compiled = jax.jit(lambda p, x: mla_apply(p, x, cfg)).lower(
             params, x).compile()
     assert ("splash_mha_fwd" in compiled.as_text()) == fused
@@ -192,7 +192,7 @@ def test_mla_forward_lowers_without_the_kernel_on_cpu():
     import dataclasses
 
     from repro.core import make_mesh
-    from repro.core.sharding import activation_rules
+    from repro.core.sharding import tracing_mesh
     from repro.models import reduced_config
     from repro.models.attention import mla_apply, mla_specs
     from repro.models.layers import init_from_specs
@@ -203,7 +203,7 @@ def test_mla_forward_lowers_without_the_kernel_on_cpu():
     loss = jax.grad(lambda p, x: jnp.sum(mla_apply(p, x, cfg)
                                          .astype(F32)))
     texts = [jax.jit(loss).lower(params, x).as_text()]
-    with activation_rules(make_mesh(1, 1), None):
+    with tracing_mesh(make_mesh(1, 1)):
         texts.append(jax.jit(loss).lower(params, x).as_text())
     for text in texts:
         assert "tpu_custom_call" not in text and "splash" not in text
